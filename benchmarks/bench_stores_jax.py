@@ -1,8 +1,8 @@
 """Beyond-paper: the five TPU array-layout stores on one counting wave, the
 headline packed-popcount vs bitmap-matmul comparison with bytes-per-transaction
-accounting, plus both Pallas support-count kernels (interpret mode on CPU:
-validated, and timed via their pure-jnp oracles, which execute the identical
-arithmetic the TPU kernels do).
+accounting, plus both Pallas support-count kernels (checked against their
+pure-jnp oracles, which are what the rows time; each row names the mode the
+kernel ran in: ``interpret`` on the CPU, ``mosaic`` on the TPU).
 """
 
 from __future__ import annotations
@@ -54,7 +54,7 @@ def run() -> list:
         f"{32}x;reduction_vs_u8=8x",
     ))
 
-    # Pallas kernels (interpret mode) on a trimmed slice: correctness + timing
+    # Pallas kernels on a trimmed slice: correctness + oracle timing
     from repro.core.stores.bitmap import candidates_to_khot
     from repro.core.stores.packed_bitmap import pack_candidates_device
     from repro.kernels.support_count import (
@@ -63,6 +63,9 @@ def run() -> list:
         support_count,
         support_count_ref,
     )
+    from repro.kernels.support_count.ops import resolve_interpret
+
+    mode = "interpret" if resolve_interpret() else "mosaic"
 
     n_small, c_small = 2048, 512
     bm = enc.bitmap[:n_small].astype(np.float32)
@@ -74,7 +77,7 @@ def run() -> list:
     got = support_count(bm, khot, kvec)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     out.append(row("kernel/support_count_ref(jnp)", ref_s * 1e6,
-                   f"N={n_small};C={c_small};interpret_validated=yes"))
+                   f"N={n_small};C={c_small};kernel_mode={mode}"))
 
     packed = enc.packed[:n_small]
     cpacked = np.asarray(
@@ -88,5 +91,5 @@ def run() -> list:
     np.testing.assert_array_equal(np.asarray(pref), np.asarray(ref))
     out.append(row("kernel/packed_support_count_ref(jnp)", pref_s * 1e6,
                    f"N={n_small};C={c_small};W={enc.n_words};"
-                   f"interpret_validated=yes"))
+                   f"kernel_mode={mode}"))
     return out

@@ -163,6 +163,9 @@ def main() -> None:
                                 else os.path.join(REPO_ROOT, "BENCH_paper.json"))
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_iterations,
         bench_mappers,

@@ -18,6 +18,7 @@ import time
 from repro.core import FrequentItemsetMiner
 from repro.core.stores import ARRAY_STORES
 from repro.data import get_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -31,6 +32,7 @@ def main() -> None:
                     help="async wave-dispatch depth (0 = fully synchronous)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_mine_t10")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"generating {args.dataset} @ scale {args.scale} ...")
     db = get_dataset(args.dataset, scale=args.scale, seed=42)

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.runtime.engine import MapReduceEngine, _shard_map
+from repro.core.runtime.engine import MapReduceEngine
 from repro.core.runtime.faults import DeviceLostError, FaultPlan
 from repro.core.runtime.job import JobProfile
 from repro.core.stores.base import ITEM_PAD
@@ -326,7 +326,7 @@ class LevelLadder:
                     local = eng._blocked_count(tr, encode_fn(cd))
                     return jax.lax.psum(local, eng.data_axes)
 
-                counts = _shard_map(
+                counts = jax.shard_map(
                     body, mesh=eng.mesh,
                     in_specs=(jax.tree.map(lambda _: data_spec, trans),
                               cand_spec),

@@ -71,11 +71,6 @@ from repro.core.stores import ARRAY_STORES, EncodedDB, pad_candidates
 from repro.core.stores.base import ITEM_PAD
 from repro.distributed.ctx import fetch_global
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # older jax: shard_map still lives under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 class PendingCounts:
     """Handle for an in-flight counting wave; ``result()`` blocks and joins.
@@ -250,7 +245,7 @@ class MapReduceEngine:
             axes_map = self.store.candidate_shard_axes()
             out_specs = {name: self._cand_pspec(axis)
                          for name, axis in axes_map.items()}
-            self._encode_jit = jax.jit(_shard_map(
+            self._encode_jit = jax.jit(jax.shard_map(
                 functools.partial(self.store.encode_candidates,
                                   f_pad=enc.f_pad),
                 mesh=self.mesh,
@@ -302,7 +297,7 @@ class MapReduceEngine:
         # only; out_specs partitions the result over ``cand``, stitching the
         # shards back into the full C axis (the mesh-level allgather). The
         # psum makes the result provably replicated over ``data`` either way.
-        fn = _shard_map(
+        fn = jax.shard_map(
             sharded,
             mesh=self.mesh,
             in_specs=(
@@ -584,7 +579,7 @@ class MapReduceEngine:
                 def sharded(p):
                     return jax.lax.psum(hist_local(p), self.data_axes)
 
-                self._job1_jit[key] = jax.jit(_shard_map(
+                self._job1_jit[key] = jax.jit(jax.shard_map(
                     sharded, mesh=self.mesh,
                     in_specs=(P(self.data_axes),), out_specs=P()))
         hist = self._job1_jit[key](dev)

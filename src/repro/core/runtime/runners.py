@@ -355,7 +355,13 @@ class SimRunner(BaseRunner):
                 self._pool = cf.ThreadPoolExecutor(max_workers=self.n_mappers)
                 self._owns_pool = True
             elif self.executor == "process":
-                self._pool = cf.ProcessPoolExecutor(max_workers=self.n_mappers)
+                import multiprocessing
+
+                # Spawned, never forked: a fork of a process that holds the
+                # accelerator runtime would inherit its claim on the chip.
+                self._pool = cf.ProcessPoolExecutor(
+                    max_workers=self.n_mappers,
+                    mp_context=multiprocessing.get_context("spawn"))
                 self._owns_pool = True
             else:
                 self._pool = self.executor

@@ -22,7 +22,11 @@ candidates are generated *speculatively* from C_k during the count, then cut
 back exactly to ``apriori_gen_matrix(L_k)`` once counts arrive
 (``filter_candidates_matrix`` keeps a superset row iff every k-subset is
 frequent — the same join+prune closure, so results are bit-identical to the
-sequential schedule at any ``inflight`` depth).
+sequential schedule at any ``inflight`` depth).  Speculation is skipped when
+the join of C_k would outgrow ``SPECULATION_CELLS``: at k=2 C_2 is every pair
+of frequent items, and its join is every triple (138M rows for 941 items),
+which neither the host generator nor the cut-back's (C_{k+1} x C_k)
+membership test can afford.
 
 Each strategy is a generator yielding ``(JobProfile, {itemset: count})`` per
 counting job, so the driver can checkpoint after every job.
@@ -40,7 +44,12 @@ from repro.core.itemsets import (
     apriori_gen_matrix,
     level_to_matrix,
 )
+from repro.core.runtime.device_loop import join_pair_count
 from repro.core.runtime.job import CountJob, JobProfile
+
+# Bound on (speculative rows x level rows) for the SPC cut-back: the device
+# filter compares every speculative row with every level row.
+SPECULATION_CELLS = 1 << 28
 
 
 def _as_matrix(level) -> np.ndarray:
@@ -72,7 +81,9 @@ def spc(runner, level, min_count: int, start_k: int, max_k: int):
         )
         spec = None
         spec_s = 0.0
-        if runner.supports_async and k + 1 <= max_k:
+        if (runner.supports_async and k + 1 <= max_k
+                and join_pair_count(cand) * cand.shape[0]
+                <= SPECULATION_CELLS):
             # Overlap: speculative C_{k+1} from C_k while the device counts.
             tg = time.perf_counter()
             spec = apriori_gen_matrix(cand)

@@ -28,16 +28,18 @@ def _kernel(t_ref, c_ref, kvec_ref, out_ref, acc_ref, *, n_fblocks: int):
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # MXU tile: (Nb, Fb) x (Fb, Cb) partial dot, f32 accumulation.
-    acc_ref[...] += jnp.dot(
-        t_ref[...], c_ref[...].T, preferred_element_type=jnp.float32
+    # MXU tile: (Nb, Fb) x (Cb, Fb)^T partial dot, f32 accumulation.
+    acc_ref[...] += jax.lax.dot_general(
+        t_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(fb == n_fblocks - 1)
     def _epilogue():
         # Fused compare + combiner: per-candidate hit count for this N block.
-        matched = acc_ref[...] == kvec_ref[...].astype(jnp.float32)[None, :]
-        partial = jnp.sum(matched.astype(jnp.int32), axis=0)
+        # kvec and the output are (1, Cb) rows: candidates run along lanes.
+        matched = acc_ref[...] == kvec_ref[...].astype(jnp.float32)
+        partial = jnp.sum(matched.astype(jnp.int32), axis=0, keepdims=True)
 
         @pl.when(nb == 0)
         def _init():
@@ -74,10 +76,11 @@ def support_count_pallas(
         in_specs=[
             pl.BlockSpec((block_n, block_f), lambda cb, nb, fb: (nb, fb)),
             pl.BlockSpec((block_c, block_f), lambda cb, nb, fb: (cb, fb)),
-            pl.BlockSpec((block_c,), lambda cb, nb, fb: (cb,)),
+            pl.BlockSpec((1, block_c), lambda cb, nb, fb: (0, cb)),
         ],
-        out_specs=pl.BlockSpec((block_c,), lambda cb, nb, fb: (cb,)),
-        out_shape=jax.ShapeDtypeStruct((c,), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_c), lambda cb, nb, fb: (0, cb)),
+        out_shape=jax.ShapeDtypeStruct((1, c), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_n, block_c), jnp.float32)],
         interpret=interpret,
-    )(bitmap.astype(jnp.bfloat16), khot.astype(jnp.bfloat16), kvec)
+    )(bitmap.astype(jnp.bfloat16), khot.astype(jnp.bfloat16),
+      kvec.reshape(1, c))[0]

@@ -1,5 +1,6 @@
 """jit'd public wrappers for the support-count kernels: pad inputs to block
-multiples, dispatch to the Pallas kernel (interpret mode on CPU), trim pads.
+multiples, dispatch to the Pallas kernel (interpret mode on CPU, Mosaic on
+TPU), trim pads.
 """
 
 from __future__ import annotations
@@ -16,6 +17,22 @@ from repro.kernels.support_count.packed import packed_support_count_pallas
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Whether a kernel call runs in Pallas interpret mode.
+
+    ``None`` picks interpret mode on the CPU backend (where the kernel body
+    is validated) and Mosaic everywhere else.  Interpret mode is refused on
+    the TPU, so a chip run never counts through the interpreter.
+    """
+    backend = jax.default_backend()
+    if interpret is None:
+        return backend == "cpu"
+    if interpret and backend == "tpu":
+        raise ValueError("interpret mode is CPU-only; on the TPU the kernel "
+                         "compiles with Mosaic (pass interpret=False or None)")
+    return bool(interpret)
 
 
 @functools.partial(
@@ -49,16 +66,15 @@ def support_count(
     """Count, for every candidate row of ``khot``, the number of ``bitmap``
     rows that contain all of its items. See kernel.py for the blocked design.
 
-    interpret=None auto-selects interpret mode off-TPU so the kernel body is
-    validated on CPU; on TPU it compiles to Mosaic.
+    ``interpret`` follows :func:`resolve_interpret`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bitmap = jnp.asarray(bitmap)
     khot = jnp.asarray(khot)
     kvec = jnp.asarray(kvec, dtype=jnp.int32)
-    # Clamp blocks for small problems (keeps the grid non-degenerate).
-    block_n = min(block_n, _round_up(bitmap.shape[0], 8))
+    # Clamp blocks for small problems (keeps the grid non-degenerate); the
+    # bf16 transaction block keeps a 16-row sublane multiple.
+    block_n = min(block_n, _round_up(bitmap.shape[0], 16))
     block_c = min(block_c, _round_up(khot.shape[0], 128))
     block_f = min(block_f, _round_up(bitmap.shape[1], 128))
     return _padded_call(
@@ -80,7 +96,7 @@ def _packed_padded_call(packed, cpacked, kvec, *, block_n, block_c, block_w,
     # Padded candidates get k=-1: a non-negative popcount never equals -1.
     kvec = jnp.pad(kvec, (0, cp - c), constant_values=-1)
     out = packed_support_count_pallas(
-        packed, cpacked, kvec,
+        packed, cpacked.T, kvec,
         block_n=block_n, block_c=block_c, block_w=block_w, interpret=interpret,
     )
     return out[:c]
@@ -93,25 +109,26 @@ def packed_support_count(
     *,
     block_n: int = 256,
     block_c: int = 256,
-    block_w: int = 32,
+    block_w: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Count, for every packed candidate row of ``cpacked``, the number of
     ``packed`` transaction rows whose AND-popcount reaches k. See packed.py
     for the blocked design.
 
-    interpret=None auto-selects interpret mode off-TPU so the kernel body is
-    validated on CPU; on TPU it compiles to Mosaic.
+    The word block is the lane dimension of the transaction block, so it is
+    either all W words (W <= 128, no padding) or ``block_w`` rounded up to a
+    multiple of 128.  ``interpret`` follows :func:`resolve_interpret`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     packed = jnp.asarray(packed, dtype=jnp.uint32)
     cpacked = jnp.asarray(cpacked, dtype=jnp.uint32)
     kvec = jnp.asarray(kvec, dtype=jnp.int32)
     # Clamp blocks for small problems (keeps the grid non-degenerate).
     block_n = min(block_n, _round_up(packed.shape[0], 8))
     block_c = min(block_c, _round_up(cpacked.shape[0], 128))
-    block_w = min(block_w, _round_up(packed.shape[1], 8))
+    w = packed.shape[1]
+    block_w = w if w <= 128 else _round_up(block_w, 128)
     return _packed_padded_call(
         packed, cpacked, kvec,
         block_n=block_n, block_c=block_c, block_w=block_w, interpret=interpret,

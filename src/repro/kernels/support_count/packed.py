@@ -6,8 +6,14 @@ Transactions and candidates arrive packed 32 item columns per uint32 word, so
 the tensors streamed through VMEM are 8x smaller than the uint8 bitmap and
 16-32x smaller than the bf16/f32 k-hot operands of the matmul kernel. The
 match-dot is replaced by pure VPU integer work: for each word w,
-``popcount(t[:, w] & c[:, w])`` contributes the number of shared items in
+``popcount(t[:, w] & c[w, :])`` contributes the number of shared items in
 that 32-column slab, accumulated over words into an (Nb, Cb) int32 scratch.
+
+Candidates come in word-major, (W, C): a word of every candidate in the
+block is then one lane-major row, and a word of every transaction is one
+column of the (Nb, Wb) block. The word loop is unrolled statically over the
+block's ``block_w`` words, so every slice is static (Mosaic has no lowering
+for a dynamic slice along lanes).
 
 Grid: (C_blocks, N_blocks, W_blocks) — same shape as the MXU kernel: for one
 candidate block we stream transaction word-blocks through VMEM, accumulate
@@ -37,21 +43,19 @@ def _kernel(t_ref, c_ref, kvec_ref, out_ref, acc_ref, *, n_wblocks: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     t = t_ref[...]  # (Nb, Wb) uint32
-    c = c_ref[...]  # (Cb, Wb) uint32
-
-    def body(w, acc):
-        tw = jax.lax.dynamic_slice_in_dim(t, w, 1, axis=1)   # (Nb, 1)
-        cw = jax.lax.dynamic_slice_in_dim(c, w, 1, axis=1)   # (Cb, 1)
-        shared = jax.lax.population_count(tw & cw.T)         # (Nb, Cb)
-        return acc + shared.astype(jnp.int32)
-
-    acc_ref[...] = jax.lax.fori_loop(0, block_w, body, acc_ref[...])
+    c = c_ref[...]  # (Wb, Cb) uint32
+    acc = acc_ref[...]
+    for w in range(block_w):  # static unroll: every slice below is static
+        shared = jax.lax.population_count(t[:, w : w + 1] & c[w : w + 1, :])
+        acc = acc + shared.astype(jnp.int32)  # (Nb, Cb)
+    acc_ref[...] = acc
 
     @pl.when(wb == n_wblocks - 1)
     def _epilogue():
         # Fused compare + combiner: per-candidate hit count for this N block.
-        matched = acc_ref[...] == kvec_ref[...][None, :]
-        partial = jnp.sum(matched.astype(jnp.int32), axis=0)
+        # kvec and the output are (1, Cb) rows: candidates run along lanes.
+        matched = acc_ref[...] == kvec_ref[...]
+        partial = jnp.sum(matched.astype(jnp.int32), axis=0, keepdims=True)
 
         @pl.when(nb == 0)
         def _init():
@@ -64,7 +68,7 @@ def _kernel(t_ref, c_ref, kvec_ref, out_ref, acc_ref, *, n_wblocks: int,
 
 def packed_support_count_pallas(
     packed: jnp.ndarray,   # (N, W) uint32, 32 item columns per word
-    cpacked: jnp.ndarray,  # (C, W) uint32 packed k-hot rows
+    cpacked_t: jnp.ndarray,  # (W, C) uint32 packed k-hot rows, word-major
     kvec: jnp.ndarray,     # (C,) int32; pad rows carry -1 (never matched)
     *,
     block_n: int = 256,
@@ -73,10 +77,10 @@ def packed_support_count_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     n, w = packed.shape
-    c, w2 = cpacked.shape
+    w2, c = cpacked_t.shape
     assert w == w2 and kvec.shape == (c,)
     assert n % block_n == 0 and c % block_c == 0 and w % block_w == 0, (
-        f"shapes ({n},{w})x({c},{w}) must divide blocks "
+        f"shapes ({n},{w})x({w},{c}) must divide blocks "
         f"({block_n},{block_c},{block_w}); pad via ops.packed_support_count"
     )
     n_wblocks = w // block_w
@@ -87,12 +91,12 @@ def packed_support_count_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_w), lambda cb, nb, wb: (nb, wb)),
-            pl.BlockSpec((block_c, block_w), lambda cb, nb, wb: (cb, wb)),
-            pl.BlockSpec((block_c,), lambda cb, nb, wb: (cb,)),
+            pl.BlockSpec((block_w, block_c), lambda cb, nb, wb: (wb, cb)),
+            pl.BlockSpec((1, block_c), lambda cb, nb, wb: (0, cb)),
         ],
-        out_specs=pl.BlockSpec((block_c,), lambda cb, nb, wb: (cb,)),
-        out_shape=jax.ShapeDtypeStruct((c,), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_c), lambda cb, nb, wb: (0, cb)),
+        out_shape=jax.ShapeDtypeStruct((1, c), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_n, block_c), jnp.int32)],
         interpret=interpret,
-    )(packed.astype(jnp.uint32), cpacked.astype(jnp.uint32),
-      kvec.astype(jnp.int32))
+    )(packed.astype(jnp.uint32), cpacked_t.astype(jnp.uint32),
+      kvec.astype(jnp.int32).reshape(1, c))[0]

@@ -3,9 +3,11 @@
 The maxtext ``128vm.sh`` idiom: every host runs the *same* command line and
 learns its role purely from environment variables — ``REPRO_COORDINATOR`` /
 ``REPRO_NUM_PROCESSES`` / ``REPRO_PROCESS_ID`` (see ``distributed.ctx``).
-This module supplies both halves of that idiom for a single machine (the CI
-substitute for a real pod: N worker *processes*, each with forced host
-devices, gloo collectives between them):
+This module supplies both halves of that idiom for a single machine: a CPU
+cluster rehearsal, the CI substitute for a real pod.  N worker *processes*
+run on the CPU backend (``JAX_PLATFORMS=cpu``), each with forced host
+devices, with gloo collectives between them; none of them touches an
+accelerator, so a chip stays with the one process that owns it.
 
 ``worker_env`` / ``launch_cluster``
     Spawn ``num_processes`` copies of an argv with the env trio set and
@@ -63,14 +65,16 @@ def find_free_port() -> int:
 
 def worker_env(coordinator: str, num_processes: int, process_id: int,
                local_devices: int = 1, base=None) -> dict:
-    """The env one worker launches with: the multihost trio plus a forced
-    per-process host device count (replacing any inherited force flag, so a
-    CI job already running under forced devices spawns clean workers)."""
+    """The env one worker launches with: the multihost trio, the CPU
+    backend, and a forced per-process host device count (replacing any
+    inherited force flag, so a CI job already running under forced devices
+    spawns clean workers)."""
     env = dict(os.environ if base is None else base)
     env["REPRO_COORDINATOR"] = coordinator
     env["REPRO_NUM_PROCESSES"] = str(num_processes)
     env["REPRO_PROCESS_ID"] = str(process_id)
     env["PYTHONUNBUFFERED"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"  # a rehearsal cluster: never the chip
     if local_devices:
         flags = _FORCE_DEVICES_RE.sub("", env.get("XLA_FLAGS", "")).strip()
         force = f"--xla_force_host_platform_device_count={local_devices}"
@@ -134,6 +138,10 @@ def _worker_main(args) -> int:
 
     ctx.initialize_multihost()  # before anything touches jax device state
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.core.miner import FrequentItemsetMiner
     from repro.core.runtime import ShardedRunner

@@ -79,6 +79,7 @@ def main() -> None:
         return
 
     from repro.data.stream import basket_stream
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import MiningService
 
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
@@ -108,6 +109,7 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=0.02)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

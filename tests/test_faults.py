@@ -557,6 +557,8 @@ def test_worker_env_trio_and_device_flags():
     assert env["XLA_FLAGS"].count("device_count") == 1
     assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]
     assert "--foo" in env["XLA_FLAGS"]
+    # Workers are a CPU rehearsal: none may claim an accelerator.
+    assert env["JAX_PLATFORMS"] == "cpu"
     assert _mctx.multihost_env(env=env) == _mctx.MultihostSpec(
         "127.0.0.1:5555", 3, 2)
 

@@ -124,6 +124,7 @@ def test_device_candidate_packer_pad_rows_never_match():
     (256, 8, 128, 3),
     (513, 9, 300, 5),
     (64, 16, 1024, 4),     # C > block
+    (40, 130, 100, 3),     # W > 128: two 128-word blocks, W padded
 ])
 def test_packed_kernel_matches_ref(n, w, c, k):
     rng = np.random.default_rng(n * 31 + c)
