@@ -166,6 +166,29 @@ def apriori_gen_matrix(level_mat: np.ndarray) -> np.ndarray:
     return cand[np.lexsort(cand.T[::-1])]
 
 
+def join_pair_count(level_mat: np.ndarray) -> int:
+    """Exact number of Agrawal-Srikant join pairs of a sorted level matrix:
+    the rows ``apriori_gen_matrix`` builds before its prune.
+
+    Rows sharing their (k-1)-prefix form contiguous groups; each group of
+    size g contributes g*(g-1)/2 pairs.  Id-independent (group boundaries
+    survive any monotone id remap), it sizes the fused ladder's device
+    ``nonzero`` and bounds the host loop's speculative join.
+    """
+    mat = np.asarray(level_mat)
+    if mat.ndim != 2 or mat.shape[0] < 2:
+        return 0
+    c, k = mat.shape
+    if k == 1:
+        return c * (c - 1) // 2
+    new_group = np.empty((c,), bool)
+    new_group[0] = True
+    new_group[1:] = ~(mat[1:, : k - 1] == mat[:-1, : k - 1]).all(axis=1)
+    starts = np.flatnonzero(new_group)
+    sizes = np.diff(np.append(starts, c))
+    return int((sizes * (sizes - 1) // 2).sum())
+
+
 def filter_candidates_matrix(cand: np.ndarray, level_mat: np.ndarray) -> np.ndarray:
     """Rows of the (C, k+1) candidate matrix whose *every* k-subset is a row
     of the sorted (L, k) ``level_mat``.

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.itemsets import join_pair_count
 from repro.core.runtime.engine import MapReduceEngine
 from repro.core.runtime.faults import DeviceLostError, FaultPlan
 from repro.core.runtime.job import JobProfile
@@ -71,28 +72,6 @@ LEVEL_UNIT = 8
 
 def _round_up(x: int, m: int) -> int:
     return -(-int(x) // int(m)) * int(m)
-
-
-def join_pair_count(level_mat: np.ndarray) -> int:
-    """Exact number of Agrawal-Srikant join pairs of a sorted level matrix.
-
-    Rows sharing their (k-1)-prefix form contiguous groups; each group of
-    size g contributes g*(g-1)/2 pairs.  Host-side and id-independent (group
-    boundaries survive any monotone id remap), it sizes the device
-    ``nonzero`` so the fused step's shapes are static per level.
-    """
-    mat = np.asarray(level_mat)
-    if mat.ndim != 2 or mat.shape[0] < 2:
-        return 0
-    c, k = mat.shape
-    if k == 1:
-        return c * (c - 1) // 2
-    new_group = np.empty((c,), bool)
-    new_group[0] = True
-    new_group[1:] = ~(mat[1:, : k - 1] == mat[:-1, : k - 1]).all(axis=1)
-    starts = np.flatnonzero(new_group)
-    sizes = np.diff(np.append(starts, c))
-    return int((sizes * (sizes - 1) // 2).sum())
 
 
 # -- jit-able join / prune / filter primitives ------------------------------

@@ -23,10 +23,10 @@ back exactly to ``apriori_gen_matrix(L_k)`` once counts arrive
 (``filter_candidates_matrix`` keeps a superset row iff every k-subset is
 frequent — the same join+prune closure, so results are bit-identical to the
 sequential schedule at any ``inflight`` depth).  Speculation is skipped when
-the join of C_k would outgrow ``SPECULATION_CELLS``: at k=2 C_2 is every pair
-of frequent items, and its join is every triple (138M rows for 941 items),
-which neither the host generator nor the cut-back's (C_{k+1} x C_k)
-membership test can afford.
+the cut-back could need more than ``SPECULATION_BYTES``: at k=2 C_2 is every
+pair of frequent items and its join is every triple (138M rows for 941
+items), which the cut-back's (C_{k+1} x L_k) membership test cannot afford
+(429 GB against a 1,000-row L_2).
 
 Each strategy is a generator yielding ``(JobProfile, {itemset: count})`` per
 counting job, so the driver can checkpoint after every job.
@@ -42,14 +42,21 @@ import numpy as np
 from repro.core.itemsets import (
     Itemset,
     apriori_gen_matrix,
+    join_pair_count,
     level_to_matrix,
 )
-from repro.core.runtime.device_loop import join_pair_count
 from repro.core.runtime.job import CountJob, JobProfile
 
-# Bound on (speculative rows x level rows) for the SPC cut-back: the device
-# filter compares every speculative row with every level row.
-SPECULATION_CELLS = 1 << 28
+# Bound on the SPC cut-back's working set.  The device filter holds one bool
+# per (speculative row, level row) cell for each of the k+1 dropped
+# positions (``memory_analysis`` of ``_filter_jit``: 3 bytes a cell at
+# k+1 = 3); |C_k| stands in for the not yet counted |L_k|.
+SPECULATION_BYTES = 1 << 30
+
+
+def _speculation_fits(cand: np.ndarray) -> bool:
+    k1 = cand.shape[1] + 1
+    return join_pair_count(cand) * cand.shape[0] * k1 <= SPECULATION_BYTES
 
 
 def _as_matrix(level) -> np.ndarray:
@@ -82,8 +89,7 @@ def spc(runner, level, min_count: int, start_k: int, max_k: int):
         spec = None
         spec_s = 0.0
         if (runner.supports_async and k + 1 <= max_k
-                and join_pair_count(cand) * cand.shape[0]
-                <= SPECULATION_CELLS):
+                and _speculation_fits(cand)):
             # Overlap: speculative C_{k+1} from C_k while the device counts.
             tg = time.perf_counter()
             spec = apriori_gen_matrix(cand)

@@ -1,16 +1,16 @@
 """Sorted-prefix store — the trie, TPU-native.
 
 A trie resolves each level by scanning the node's ordered children; the
-array-layout dual is an ordered search of the candidate's next item inside the
-*sorted transaction row* — ``searchsorted`` per level (the ordered-scan cost
-model: the item is ranked against the row's L slots) instead of the
-perfect-hash store's O(1) gather.
+array-layout dual scans the *transaction row* for the candidate's next item —
+one comparison against each of the row's L slots per level (the scan cost
+model) instead of the perfect-hash store's O(1) gather.  The count does not
+use the row's sort order: a binary search over it is a per-row gather, which
+the TPU runs one index at a time (a host-loop mine of T10I4D100K at support
+0.0025 did not finish in 23 minutes on a v5e that way).
 """
 
 from __future__ import annotations
 
-import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.core.stores.base import DeltaCountMixin, EncodedDB
@@ -48,20 +48,7 @@ class SortedPrefixStore(DeltaCountMixin):
         k = cand.shape[1]
 
         def level_found(items):  # items: (C,) -> (Nb, C) bool
-            # An item is in the sorted row iff its right insertion point lies
-            # past its left one. ``compare_all`` ranks an item by comparing
-            # it with every slot of the row: no per-row gather, which the TPU
-            # runs one index at a time (with the binary ``scan``, a host-loop
-            # mine of T10I4D100K at support 0.0025 did not finish in 23
-            # minutes on a v5e).
-            def per_row(row):
-                lo = jnp.searchsorted(row, items, side="left",
-                                      method="compare_all")
-                hi = jnp.searchsorted(row, items, side="right",
-                                      method="compare_all")
-                return hi > lo
-
-            return jax.vmap(per_row)(padded)
+            return jnp.any(padded[:, :, None] == items[None, None, :], axis=1)
 
         matched = level_found(cand[:, 0])
         for level in range(1, k):

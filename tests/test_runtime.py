@@ -701,16 +701,16 @@ def test_device_filter_matches_host(t10_db):
             np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("cells", [0, 1 << 28])
+@pytest.mark.parametrize("budget", [0, 1 << 28])
 def test_spc_speculation_bound_keeps_results(t10_db, oracle, monkeypatch,
-                                             cells):
-    """A level whose join would outgrow SPECULATION_CELLS is generated after
-    its count instead of speculated during it; either way the mine is
+                                             budget):
+    """A level whose cut-back could outgrow SPECULATION_BYTES is generated
+    after its count instead of speculated during it; either way the mine is
     exact."""
     from repro.core.itemsets import filter_candidates_matrix
     from repro.core.runtime import strategies
 
-    monkeypatch.setattr(strategies, "SPECULATION_CELLS", cells)
+    monkeypatch.setattr(strategies, "SPECULATION_BYTES", budget)
     calls = []
     monkeypatch.setattr(JaxRunner, "filter_candidates",
                         lambda self, spec, lvl: calls.append(1) or
@@ -718,7 +718,7 @@ def test_spc_speculation_bound_keeps_results(t10_db, oracle, monkeypatch,
     res = FrequentItemsetMiner(min_support=MIN_SUPPORT,
                                runner=JaxRunner()).mine(t10_db)
     assert res.itemsets == oracle
-    assert bool(calls) == (cells > 0)
+    assert bool(calls) == (budget > 0)
 
 
 @pytest.mark.parametrize("store", list(ARRAY_STORES))
