@@ -39,6 +39,7 @@ from repro.core.itemsets import Itemset, level_to_matrix, sort_level
 from repro.core.runtime import BaseRunner, JobProfile, make_runner
 from repro.core.runtime import strategies
 from repro.core.runtime.faults import DeviceLostError
+from repro.core.runtime.spans import span
 
 # Back-compat alias: the old per-level stats type is the unified JobProfile.
 LevelStats = JobProfile
@@ -130,11 +131,14 @@ class FrequentItemsetMiner:
     def _make_runner(self) -> BaseRunner:
         if self.runner is not None:
             return self.runner
-        return make_runner(store=self.store, mesh=self.mesh,
-                           data_axes=self.data_axes, cand_axes=self.cand_axes,
-                           block_n=self.block_n, cand_block=self.cand_block,
-                           inflight=self.inflight,
-                           encode_ahead=self.encode_ahead)
+        with span("repro.build"):
+            return make_runner(store=self.store, mesh=self.mesh,
+                               data_axes=self.data_axes,
+                               cand_axes=self.cand_axes,
+                               block_n=self.block_n,
+                               cand_block=self.cand_block,
+                               inflight=self.inflight,
+                               encode_ahead=self.encode_ahead)
 
     def _config(self, runner: BaseRunner) -> dict:
         """The run configuration stamped into checkpoints; a checkpoint from
@@ -157,20 +161,21 @@ class FrequentItemsetMiner:
         set, or deterministically recomputes from scratch otherwise.
         Either way the result is bit-identical to a fault-free run.
         """
-        n = len(transactions)
-        min_count = max(1, int(np.ceil(self.min_support * n)))
-        runner = self._make_runner()
-        restarts = 0
-        while True:
-            self.active_runner = runner  # introspection: tests/benchmarks
-            try:
-                return self._mine_once(runner, transactions, n, min_count)
-            except DeviceLostError as err:
-                restarts += 1
-                runner.close(wait=False)
-                if restarts > self.elastic_restarts:
-                    raise
-                runner = self._elastic_rebuild(runner, err)
+        with span("repro.mine"):
+            n = len(transactions)
+            min_count = max(1, int(np.ceil(self.min_support * n)))
+            runner = self._make_runner()
+            restarts = 0
+            while True:
+                self.active_runner = runner  # introspection: tests/benchmarks
+                try:
+                    return self._mine_once(runner, transactions, n, min_count)
+                except DeviceLostError as err:
+                    restarts += 1
+                    runner.close(wait=False)
+                    if restarts > self.elastic_restarts:
+                        raise
+                    runner = self._elastic_rebuild(runner, err)
 
     def _elastic_rebuild(self, runner: BaseRunner,
                          err: DeviceLostError) -> BaseRunner:
@@ -238,14 +243,16 @@ class FrequentItemsetMiner:
             runner, level_mat, min_count, start_k=k, max_k=self.max_k
         ):
             levels.append(stats)
-            for s, c in freq_dense.items():
-                orig = tuple(int(item_map[i]) for i in s)
-                itemsets[orig] = int(c)
-            # A combined (FPC/DPC) wave yields mixed itemset sizes; the next
-            # level the strategy continues from — and the only thing a
-            # restore may rebuild into a (C, k) matrix — is the top-k slice.
-            top_k = max((len(s) for s in freq_dense), default=0)
-            level = sort_level(s for s in freq_dense if len(s) == top_k)
+            with span("repro.collect", rows=len(freq_dense)):
+                for s, c in freq_dense.items():
+                    orig = tuple(int(item_map[i]) for i in s)
+                    itemsets[orig] = int(c)
+                # A combined (FPC/DPC) wave yields mixed itemset sizes; the
+                # next level the strategy continues from — and the only
+                # thing a restore may rebuild into a (C, k) matrix — is the
+                # top-k slice.
+                top_k = max((len(s) for s in freq_dense), default=0)
+                level = sort_level(s for s in freq_dense if len(s) == top_k)
             self._checkpoint(itemsets, levels, level, stats.k + 1, item_map,
                              n, min_count, self._config(runner),
                              fault_plan=getattr(runner, "fault_plan", None))
@@ -269,21 +276,22 @@ class FrequentItemsetMiner:
             return
         from repro.distributed import checkpoint as ckpt
 
-        # ``level`` arrives in dense ids; persist original ids so a restart
-        # (which recomputes the dense remap) stays consistent.
-        orig_level = [[int(item_map[i]) for i in s] for s in level]
-        extra = {
-            "itemsets": [[list(s), c] for s, c in itemsets.items()],
-            "levels": [dataclasses.asdict(s) for s in levels],
-            "level": orig_level,
-            "next_k": next_k,
-            "n": n,
-            "min_count": min_count,
-            "config": json.dumps(config, sort_keys=True),
-        }
-        ckpt.save(self.checkpoint_dir, step=next_k,
-                  tree={"item_map": np.asarray(item_map)}, extra=extra,
-                  fault_plan=fault_plan)
+        with span("repro.checkpoint", k=next_k - 1):
+            # ``level`` arrives in dense ids; persist original ids so a
+            # restart (which recomputes the dense remap) stays consistent.
+            orig_level = [[int(item_map[i]) for i in s] for s in level]
+            extra = {
+                "itemsets": [[list(s), c] for s, c in itemsets.items()],
+                "levels": [dataclasses.asdict(s) for s in levels],
+                "level": orig_level,
+                "next_k": next_k,
+                "n": n,
+                "min_count": min_count,
+                "config": json.dumps(config, sort_keys=True),
+            }
+            ckpt.save(self.checkpoint_dir, step=next_k,
+                      tree={"item_map": np.asarray(item_map)}, extra=extra,
+                      fault_plan=fault_plan)
 
     def _try_restore(self, n: int, min_count: int, config: dict):
         if self.checkpoint_dir is None or \

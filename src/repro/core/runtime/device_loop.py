@@ -49,7 +49,6 @@ shape, so a second mine over the same shapes is compile-free.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
@@ -61,6 +60,7 @@ from repro.core.itemsets import join_pair_count
 from repro.core.runtime.engine import MapReduceEngine
 from repro.core.runtime.faults import DeviceLostError, FaultPlan
 from repro.core.runtime.job import JobProfile
+from repro.core.runtime.spans import span
 from repro.core.stores.base import ITEM_PAD
 
 # Pad quanta: candidate and level row counts round up to these so the jit
@@ -449,27 +449,27 @@ class LevelLadder:
             if n_pairs == 0:
                 return
             self._check_fault(k1)
-            t0 = time.perf_counter()
             trim_s = 0.0
-            if self.trim:
-                self._trim(k1)
-                trim_s = time.perf_counter() - t0
-            c_pad = _round_up(
-                n_pairs, CAND_UNIT * max(1, self.engine.n_cand_shards))
-            step = self._get_step(k1, c_pad)
-            freq_dev, counts_dev, stats_dev = step(
-                self._trans, self._lvl_dev, np.int32(self._n_valid),
-                np.int32(self.min_count))
-            stats = np.asarray(jax.device_get(stats_dev))
-            n_freq, n_cand = int(stats[0]), int(stats[1])
-            freq_l = np.asarray(jax.device_get(freq_dev[:n_freq]))
-            counts = np.asarray(
-                jax.device_get(counts_dev[:n_freq])).astype(np.int64)
-            wall = time.perf_counter() - t0
+            with span("repro.ladder.level", k=k1) as lvl:
+                if self.trim:
+                    with span("repro.ladder.trim") as tr:
+                        self._trim(k1)
+                    trim_s = tr.seconds
+                c_pad = _round_up(
+                    n_pairs, CAND_UNIT * max(1, self.engine.n_cand_shards))
+                step = self._get_step(k1, c_pad)
+                freq_dev, counts_dev, stats_dev = step(
+                    self._trans, self._lvl_dev, np.int32(self._n_valid),
+                    np.int32(self.min_count))
+                stats = np.asarray(jax.device_get(stats_dev))
+                n_freq, n_cand = int(stats[0]), int(stats[1])
+                freq_l = np.asarray(jax.device_get(freq_dev[:n_freq]))
+                counts = np.asarray(
+                    jax.device_get(counts_dev[:n_freq])).astype(np.int64)
             prof = JobProfile(
-                k=k1, n_candidates=n_cand, n_frequent=n_freq, seconds=wall,
-                count_seconds=wall - trim_s, reduce_seconds=trim_s,
-                n_pad=self._n_pad, f_pad=self._f_pad,
+                k=k1, n_candidates=n_cand, n_frequent=n_freq,
+                seconds=lvl.seconds, count_seconds=lvl.seconds - trim_s,
+                reduce_seconds=trim_s, n_pad=self._n_pad, f_pad=self._f_pad,
             )
             # Translate ladder ids -> miner dense ids at the yield boundary
             # (monotone map: rows stay lex-sorted for the driver/checkpoint).

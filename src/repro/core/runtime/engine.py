@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.runtime.spans import span
 from repro.core.stores import ARRAY_STORES, EncodedDB, pad_candidates
 from repro.core.stores.base import ITEM_PAD
 from repro.distributed.ctx import fetch_global
@@ -361,8 +362,8 @@ class MapReduceEngine:
         collective, which is safe exactly because this queue is strict FIFO:
         every process fetches the same results in the same order)."""
         pending, slot, dev, c = self._queue.popleft()
-        counts = fetch_global(dev)
-        pending._parts[slot] = counts[:c].astype(np.int64)
+        with span("repro.fetch", rows=c):
+            pending._parts[slot] = fetch_global(dev)[:c].astype(np.int64)
 
     def drain_ready(self) -> int:
         """Fetch every *leading* queue entry whose device result is already

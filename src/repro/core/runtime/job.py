@@ -9,15 +9,23 @@ Java-equivalent and array-store paths side by side in one table.
 Phase fields (seconds; a backend leaves phases it does not have at 0.0):
 
 ====================  ====================================================
-``gen_seconds``       candidate generation (host ``apriori_gen_matrix``,
-                      or max-over-mappers apriori-gen in the simulator)
+``gen_seconds``       candidate generation (host ``apriori_gen_matrix``
+                      and the SPC cut-back, the ``repro.gen`` spans; or
+                      max-over-mappers apriori-gen in the simulator)
 ``build_seconds``     candidate-structure build (max-over-mappers tree /
                       trie construction in the simulator)
-``encode_seconds``    host->device candidate encode + dispatch (JAX)
-``count_seconds``     mapper counting: device wait (JAX) or
-                      max-over-mappers transaction scan (simulator)
-``reduce_seconds``    reducer: partial-count merge (simulator) or
-                      host-side threshold/fetch bookkeeping (JAX)
+``encode_seconds``    JAX: the wave's dispatch (``repro.dispatch``): host
+                      encode and dispatch of every chunk, *and* the fetches
+                      of older chunks forced to keep at most ``inflight``
+                      outstanding — at the default depth of 1 most of the
+                      device wait lands here
+``count_seconds``     mapper counting: the wait for the wave's remaining
+                      chunks (JAX, ``repro.wait``), the level step (fused
+                      ladder), or max-over-mappers transaction scan
+                      (simulator); for k = 1 on JAX, the whole of Job1
+``reduce_seconds``    reducer: partial-count merge (simulator), or the
+                      fused ladder's on-device trim; the JAX runners' own
+                      jobs never set it
 ====================  ====================================================
 
 ``mapper_seconds`` keeps the simulator's per-mapper wall clocks so its

@@ -82,6 +82,7 @@ from repro.core.runtime.faults import (
     partial_digest,
 )
 from repro.core.runtime.job import CountJob, JobProfile
+from repro.core.runtime.spans import span
 from repro.core.sequential import SEQUENTIAL_STORES
 from repro.core.stores import encode_db_from_padded, padded_from_transactions
 from repro.core.stores.base import EncodedDB, dense_remap_padded
@@ -664,11 +665,11 @@ class _ChunkedPending:
         return all(p.done for p in self._parts)
 
     def result(self) -> Tuple[np.ndarray, JobProfile]:
-        t0 = time.perf_counter()
-        total = np.zeros((int(self._job.cand.shape[0]),), np.int64)
-        for p in self._parts:
-            total += p.result()
-        wait_s = time.perf_counter() - t0
+        with span("repro.wait") as w:
+            total = np.zeros((int(self._job.cand.shape[0]),), np.int64)
+            for p in self._parts:
+                total += p.result()
+        wait_s = w.seconds
         prof = JobProfile(
             k=self._job.k, n_candidates=self._job.n_candidates,
             seconds=self._encode_s + wait_s,
@@ -696,9 +697,9 @@ class _JaxPending:
         return self._pending.poll()
 
     def result(self) -> Tuple[np.ndarray, JobProfile]:
-        t0 = time.perf_counter()
-        counts = self._pending.result()
-        wait_s = time.perf_counter() - t0
+        with span("repro.wait") as w:
+            counts = self._pending.result()
+        wait_s = w.seconds
         prof = JobProfile(
             k=self._job.k, n_candidates=self._job.n_candidates,
             seconds=self._encode_s + wait_s,
@@ -776,30 +777,30 @@ class JaxRunner(BaseRunner):
         # The single host pass over the raw lists; everything downstream
         # (Job1, dense re-encode, counting) is vectorized or on device.
         self._reader = None
-        self._padded_raw, self._n_raw = padded_from_transactions(transactions)
+        with span("repro.ingest", rows=len(transactions)):
+            self._padded_raw, self._n_raw = padded_from_transactions(
+                transactions)
         self._raw_digest = None  # lazily computed on first place()
 
     def job1(self) -> Tuple[np.ndarray, JobProfile]:
-        t0 = time.perf_counter()
-        if self._reader is not None:
-            # Per-chunk device histograms, summed on host: bincount is
-            # additive over disjoint blocks, so this equals the whole-DB job.
-            hist = np.zeros((self._n_raw,), np.int64)
-            n_chunks = 0
-            for chunk in self._reader.chunks():
-                hist += self.engine.count_items_device(chunk, self._n_raw)
-                n_chunks += 1
-            wall = time.perf_counter() - t0
-            prof = JobProfile(k=1, n_candidates=int(np.count_nonzero(hist)),
-                              seconds=wall, count_seconds=wall,
-                              chunks=n_chunks)
-            return hist, prof
-        hist = self.engine.count_items_device(self._padded_raw, self._n_raw)
-        wall = time.perf_counter() - t0
+        n_chunks = 0
+        with span("repro.job1") as s:
+            if self._reader is not None:
+                # Per-chunk device histograms, summed on host: bincount is
+                # additive over disjoint blocks, so this equals the whole-DB
+                # job.
+                hist = np.zeros((self._n_raw,), np.int64)
+                for chunk in self._reader.chunks():
+                    hist += self.engine.count_items_device(chunk, self._n_raw)
+                    n_chunks += 1
+            else:
+                hist = self.engine.count_items_device(self._padded_raw,
+                                                      self._n_raw)
         # n_candidates = distinct items actually observed — the same Job1
         # semantic as SimRunner, keeping k=1 rows comparable across backends.
         prof = JobProfile(k=1, n_candidates=int(np.count_nonzero(hist)),
-                          seconds=wall, count_seconds=wall)
+                          seconds=s.seconds, count_seconds=s.seconds,
+                          chunks=n_chunks)
         return hist, prof
 
     def place(self, item_map: np.ndarray) -> None:
@@ -817,24 +818,29 @@ class JaxRunner(BaseRunner):
             # a cached EncodedDB *is* the whole-DB materialization.
             self._chunk_item_map = np.asarray(item_map, np.int64)
             return
-        if self._raw_digest is None:
-            self._raw_digest = dataset_digest(self._padded_raw)
         item_arr = np.asarray(item_map, np.int64)
         f = len(item_arr)
         f_pad = ((f // 128) + 1) * 128  # EncodedDB's padded item-column count
-        key = (self._raw_digest, self.engine.store_name, f_pad,
-               dataset_digest(item_arr))
-        enc = DATASET_CACHE.get_or_build(key, lambda: self._encode(item_arr))
-        self.engine.place(enc)
+        with span("repro.place"):
+            with span("repro.place.digest"):
+                if self._raw_digest is None:
+                    self._raw_digest = dataset_digest(self._padded_raw)
+                key = (self._raw_digest, self.engine.store_name, f_pad,
+                       dataset_digest(item_arr))
+            enc = DATASET_CACHE.get_or_build(
+                key, lambda: self._encode(item_arr))
+            with span("repro.place.put"):
+                self.engine.place(enc)
 
     def _encode(self, item_map: np.ndarray):
         """Vectorized dense re-encode over the frequent items (Apriori
         property: no candidate may contain an infrequent item).  The remap
         itself is shared with the serving layer's per-block encode
         (``dense_remap_padded``), so batch and streaming blocks agree."""
-        dense = dense_remap_padded(self._padded_raw, item_map,
-                                   n_raw=self._n_raw)
-        return encode_db_from_padded(dense, n_items=len(item_map))
+        with span("repro.place.encode", rows=self._padded_raw.shape[0]):
+            dense = dense_remap_padded(self._padded_raw, item_map,
+                                       n_raw=self._n_raw)
+            return encode_db_from_padded(dense, n_items=len(item_map))
 
     def encode_block(self, padded_raw: np.ndarray,
                      item_map: np.ndarray) -> "EncodedDB":
@@ -891,9 +897,9 @@ class JaxRunner(BaseRunner):
                 raise DeviceLostError(lost=spec.lost, k=job.k)
         if self._reader is not None:
             return self._count_chunked_async(job)
-        t0 = time.perf_counter()
-        pending = self.engine.count_candidates_async(job.cand)
-        return _JaxPending(self, job, pending, time.perf_counter() - t0)
+        with span("repro.dispatch", k=job.k, rows=job.n_candidates) as d:
+            pending = self.engine.count_candidates_async(job.cand)
+        return _JaxPending(self, job, pending, d.seconds)
 
     def _count_chunked_async(self, job: CountJob) -> _ChunkedPending:
         """Stream the reader through the wave: per chunk, the serving path's
@@ -903,12 +909,12 @@ class JaxRunner(BaseRunner):
         once ``inflight`` chunk counts are outstanding, so dispatch never
         runs ahead of the device by more than the queue."""
         assert self._chunk_item_map is not None, "call place(item_map) first"
-        t0 = time.perf_counter()
         parts = []
-        for chunk in self._reader.chunks():
-            enc = self.encode_block(chunk, self._chunk_item_map)
-            parts.append(self.engine.count_block_async(enc, job.cand))
-        return _ChunkedPending(self, job, parts, time.perf_counter() - t0)
+        with span("repro.dispatch", k=job.k, rows=job.n_candidates) as d:
+            for chunk in self._reader.chunks():
+                enc = self.encode_block(chunk, self._chunk_item_map)
+                parts.append(self.engine.count_block_async(enc, job.cand))
+        return _ChunkedPending(self, job, parts, d.seconds)
 
 
 class ShardedRunner(JaxRunner):

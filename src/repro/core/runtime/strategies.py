@@ -46,6 +46,7 @@ from repro.core.itemsets import (
     level_to_matrix,
 )
 from repro.core.runtime.job import CountJob, JobProfile
+from repro.core.runtime.spans import span
 
 # Bound on the SPC cut-back's working set.  The device filter holds one bool
 # per (speculative row, level row) cell for each of the k+1 dropped
@@ -78,9 +79,9 @@ def spc(runner, level, min_count: int, start_k: int, max_k: int):
     if not mat.size or start_k > max_k:
         return
     k = start_k
-    tg = time.perf_counter()
-    cand = apriori_gen_matrix(mat)
-    gen_s = time.perf_counter() - tg
+    with span("repro.gen", k=k, rows=mat.shape[0]) as g:
+        cand = apriori_gen_matrix(mat)
+    gen_s = g.seconds
     while cand.size and k <= max_k:
         t0 = time.perf_counter()
         pending = runner.count_async(
@@ -91,24 +92,27 @@ def spc(runner, level, min_count: int, start_k: int, max_k: int):
         if (runner.supports_async and k + 1 <= max_k
                 and _speculation_fits(cand)):
             # Overlap: speculative C_{k+1} from C_k while the device counts.
-            tg = time.perf_counter()
-            spec = apriori_gen_matrix(cand)
-            spec_s = time.perf_counter() - tg
+            with span("repro.gen", k=k + 1, rows=cand.shape[0]) as g:
+                spec = apriori_gen_matrix(cand)
+            spec_s = g.seconds
         counts, prof = pending.result()
         keep = counts >= min_count
         freq_mat, freq_counts = cand[keep], counts[keep]
-        tg = time.perf_counter()
         if k + 1 > max_k:
             next_cand = np.zeros((0, mat.shape[1] + 2), np.int32)
-        elif spec is not None:
-            # Exact cut back to apriori_gen_matrix(L_k): keep a speculative
-            # row iff all its k-subsets are frequent.  The runner picks the
-            # implementation — host subset loop, or the jit-compiled
-            # membership filter on device-backed runners.
-            next_cand = runner.filter_candidates(spec, freq_mat)
+            next_gen_s = 0.0
         else:
-            next_cand = apriori_gen_matrix(freq_mat)
-        next_gen_s = spec_s + time.perf_counter() - tg
+            with span("repro.gen", k=k + 1, rows=freq_mat.shape[0]) as g:
+                if spec is not None:
+                    # Exact cut back to apriori_gen_matrix(L_k): keep a
+                    # speculative row iff all its k-subsets are frequent.
+                    # The runner picks the implementation — host subset
+                    # loop, or the jit-compiled membership filter on
+                    # device-backed runners.
+                    next_cand = runner.filter_candidates(spec, freq_mat)
+                else:
+                    next_cand = apriori_gen_matrix(freq_mat)
+            next_gen_s = spec_s + g.seconds
         prof.k = k
         prof.n_candidates = int(cand.shape[0])
         prof.n_frequent = int(freq_mat.shape[0])
@@ -137,10 +141,9 @@ def _combined(runner, level, min_count, start_k, max_k, should_extend):
     k = start_k
     while mat.size and k <= max_k:
         t0 = time.perf_counter()
-        gen_s = 0.0
-        tg = time.perf_counter()
-        cand = apriori_gen_matrix(mat)
-        gen_s += time.perf_counter() - tg
+        with span("repro.gen", k=k, rows=mat.shape[0]) as g:
+            cand = apriori_gen_matrix(mat)
+        gen_s = g.seconds
         waves: List[np.ndarray] = []
         pendings: List = []
         while cand.size:
@@ -151,9 +154,11 @@ def _combined(runner, level, min_count, start_k, max_k, should_extend):
             )))
             if k + len(waves) - 1 >= max_k or not should_extend(waves):
                 break
-            tg = time.perf_counter()
-            cand = apriori_gen_matrix(cand)  # speculative: join/prune against C_k
-            gen_s += time.perf_counter() - tg
+            # Speculative: join/prune against C_k.
+            with span("repro.gen", k=k + len(waves),
+                      rows=cand.shape[0]) as g:
+                cand = apriori_gen_matrix(cand)
+            gen_s += g.seconds
         if not waves:
             return
         n_cands = sum(w.shape[0] for w in waves)

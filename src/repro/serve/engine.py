@@ -72,14 +72,15 @@ overlaps the host's next-batch ingest; results are joined non-blockingly
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
-import time
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.itemsets import Itemset, apriori_gen_matrix, level_to_matrix
 from repro.core.runtime import BaseRunner, CountJob, make_runner
+from repro.core.runtime.spans import span
 from repro.core.stores.base import (
     ITEM_PAD,
     padded_from_transactions,
@@ -178,12 +179,14 @@ class _TrackedLevel:
 
     def rows_of(self, queries: np.ndarray) -> np.ndarray:
         """int64[Q] row index per query row; -1 where untracked."""
-        if self._index is None:
-            self._index = {row.tobytes(): i for i, row in enumerate(self.cand)}
-        q = np.ascontiguousarray(queries, dtype=np.int32)
-        return np.fromiter(
-            (self._index.get(row.tobytes(), -1) for row in q),
-            dtype=np.int64, count=q.shape[0])
+        with span("repro.svc.lookup", rows=queries.shape[0]):
+            if self._index is None:
+                self._index = {row.tobytes(): i
+                               for i, row in enumerate(self.cand)}
+            q = np.ascontiguousarray(queries, dtype=np.int32)
+            return np.fromiter(
+                (self._index.get(row.tobytes(), -1) for row in q),
+                dtype=np.int64, count=q.shape[0])
 
 
 # One in-flight delta record: every per-level job dispatched for one signed
@@ -311,40 +314,45 @@ class MiningService:
         counts are *subtracted* exactly as the new block's are added, so
         tracked counts always equal a fresh count over the live window.
         """
-        t0 = time.perf_counter()
-        batch = [list(t) for t in transactions]
-        added = evicted = 0
-        jobs0 = self.delta_jobs
-        cap = self.n_slots * self.slot_size
-        for i in range(0, len(batch), self.slot_size):
-            block = batch[i : i + self.slot_size]
-            if self.eviction == "basket":
-                overflow = self._window_n + len(block) - cap
-                if overflow > 0:
-                    evicted += self._evict_baskets(overflow)
-            elif len(self._slots) == self.n_slots:
-                old = self._slots.popleft()
-                self._apply_padded(old.padded, sign=-1)
-                evicted += len(old.transactions)
-                self._window_n -= len(old.transactions)
-            padded, _ = padded_from_transactions(block)
-            slot = _Slot(transactions=block, padded=padded, seq=self._seq)
-            self._seq += 1
-            self._slots.append(slot)
-            self._apply_padded(padded, sign=+1)
-            self._window_n += len(block)
-            added += len(block)
-        self.total_ingested += added
-        self.total_evicted += evicted
-        # Off-query-path upkeep: join whatever delta results already landed
-        # and advance any in-flight background refresh by one unit — both
-        # non-blocking, so ingest latency stays bounded.
-        self._drain_deltas(block=False)
-        self._pump_refresh(block=False)
+        with span("repro.svc.ingest", rows=len(transactions)) as s:
+            with span("repro.svc.slot", rows=len(transactions)):
+                batch = [list(t) for t in transactions]
+                blocks = [batch[i : i + self.slot_size]
+                          for i in range(0, len(batch), self.slot_size)]
+                padded_blocks = [padded_from_transactions(b)[0]
+                                 for b in blocks]
+            added = evicted = 0
+            jobs0 = self.delta_jobs
+            cap = self.n_slots * self.slot_size
+            for block, padded in zip(blocks, padded_blocks):
+                if self.eviction == "basket":
+                    overflow = self._window_n + len(block) - cap
+                    if overflow > 0:
+                        with span("repro.svc.evict", rows=overflow):
+                            evicted += self._evict_baskets(overflow)
+                elif len(self._slots) == self.n_slots:
+                    old = self._slots.popleft()
+                    with span("repro.svc.evict", rows=len(old.padded)):
+                        self._apply_padded(old.padded, sign=-1)
+                    evicted += len(old.transactions)
+                    self._window_n -= len(old.transactions)
+                slot = _Slot(transactions=block, padded=padded, seq=self._seq)
+                self._seq += 1
+                self._slots.append(slot)
+                self._apply_padded(padded, sign=+1)
+                self._window_n += len(block)
+                added += len(block)
+            self.total_ingested += added
+            self.total_evicted += evicted
+            # Off-query-path upkeep: join whatever delta results already
+            # landed and advance any in-flight background refresh by one
+            # unit — both non-blocking, so ingest latency stays bounded.
+            self._drain_deltas(block=False)
+            self._pump_refresh(block=False)
         return IngestReport(
             n_ingested=added, n_evicted=evicted, n_slots=len(self._slots),
             window=self._window_n, delta_jobs=self.delta_jobs - jobs0,
-            seconds=time.perf_counter() - t0)
+            seconds=s.seconds)
 
     def evict(self, n: int = 1) -> IngestReport:
         """Evict the ``n`` oldest baskets (sub-slot granularity).
@@ -354,16 +362,16 @@ class MiningService:
         one-row ``apply_delta`` — so the window stays bit-identical to a
         batch mine over the remaining baskets at any granularity.
         """
-        t0 = time.perf_counter()
         jobs0 = self.delta_jobs
-        evicted = self._evict_baskets(int(n))
-        self.total_evicted += evicted
-        self._drain_deltas(block=False)
-        self._pump_refresh(block=False)
+        with span("repro.svc.evict", rows=int(n)) as s:
+            evicted = self._evict_baskets(int(n))
+            self.total_evicted += evicted
+            self._drain_deltas(block=False)
+            self._pump_refresh(block=False)
         return IngestReport(
             n_ingested=0, n_evicted=evicted, n_slots=len(self._slots),
             window=self._window_n, delta_jobs=self.delta_jobs - jobs0,
-            seconds=time.perf_counter() - t0)
+            seconds=s.seconds)
 
     def _evict_baskets(self, n: int) -> int:
         """Remove the ``n`` oldest baskets from the head of the window,
@@ -418,12 +426,15 @@ class MiningService:
             return  # all-empty transactions support nothing: exact no-op
         enc = None
         jobs: List[Tuple[int, object]] = []
-        for k, tl in self._levels.items():
-            if tl.cand.size:
-                if enc is None:
-                    enc = self.runner.encode_block(padded, self._item_map)
-                jobs.append((k, self.runner.count_block_async(enc, tl.cand)))
-                self.delta_jobs += 1
+        with span("repro.svc.delta", rows=padded.shape[0]):
+            for k, tl in self._levels.items():
+                if tl.cand.size:
+                    if enc is None:
+                        enc = self.runner.encode_block(padded,
+                                                       self._item_map)
+                    jobs.append(
+                        (k, self.runner.count_block_async(enc, tl.cand)))
+                    self.delta_jobs += 1
         if jobs:
             self._pending_deltas.append((sign, padded.shape[0], jobs))
 
@@ -438,16 +449,19 @@ class MiningService:
 
         ``block=False`` joins only the leading records whose every per-level
         job has already finished on device (``poll``) — never blocks, so the
-        ingest path can keep counts near-current for free.
+        ingest path can keep counts near-current for free.  Only the
+        blocking join is a span (``repro.svc.drain``).
         """
-        while self._pending_deltas:
-            sign, n, jobs = self._pending_deltas[0]
-            if not block and not all(p.poll() for _, p in jobs):
-                break
-            for k, pend in jobs:
-                self._levels[k].counts += sign * pend.result()
-            self._drained_since_compact += n
-            self._pending_deltas.pop(0)
+        with (span("repro.svc.drain", rows=self._undrained()) if block
+              else contextlib.nullcontext()):
+            while self._pending_deltas:
+                sign, n, jobs = self._pending_deltas[0]
+                if not block and not all(p.poll() for _, p in jobs):
+                    break
+                for k, pend in jobs:
+                    self._levels[k].counts += sign * pend.result()
+                self._drained_since_compact += n
+                self._pending_deltas.pop(0)
         if not self._pending_deltas:
             self._maybe_compact()
 
@@ -459,7 +473,8 @@ class MiningService:
         if (self._drained_since_compact
                 < self.compact_churn * max(1, self._window_n)):
             return
-        self._compact()
+        with span("repro.svc.compact"):
+            self._compact()
 
     def _compact(self) -> None:
         """Prune tracked rows outside the generatable closure at the track
@@ -502,7 +517,13 @@ class MiningService:
         ``s * window``, answer immediately from current counts with an
         :class:`ErrorCertificate`; beyond the budget, fall back to exact.
         """
-        t0 = time.perf_counter()
+        with span("repro.svc.query") as s:
+            res = self._query(min_support, staleness)
+        res.seconds = s.seconds
+        return res
+
+    def _query(self, min_support: Optional[float],
+               staleness: Optional[float]) -> ServeResult:
         ms = self.min_support if min_support is None else float(min_support)
         self._pump_refresh(block=False)
         n = self._window_n
@@ -510,7 +531,7 @@ class MiningService:
         if n == 0:
             return ServeResult(
                 itemsets={}, min_count=1, n_transactions=0, refreshed=False,
-                stale_reason=None, seconds=time.perf_counter() - t0,
+                stale_reason=None,
                 certificate=ErrorCertificate(0, 1, 0, 0, live),
                 refresh_in_flight=live)
         min_count = max(1, int(np.ceil(ms * n)))
@@ -546,7 +567,6 @@ class MiningService:
         return ServeResult(itemsets=served, min_count=min_count,
                            n_transactions=n, refreshed=refreshed,
                            stale_reason=reason,
-                           seconds=time.perf_counter() - t0,
                            certificate=cert, refresh_in_flight=live)
 
     def _serve_approx(self, min_count: int, n: int):
@@ -577,34 +597,40 @@ class MiningService:
     def _serve_from_tracked(self, min_count: int) -> Optional[Dict[Itemset, int]]:
         """Walk the Apriori lattice from the delta-maintained counts; None if
         any generated candidate escapes the tracked lattice (stale)."""
-        l1_raw = np.nonzero(self._hist >= min_count)[0]
-        # Raw ids outside the refresh item map resolve to -1 via the lookup's
-        # guard slot — a newly frequent item is by itself a staleness signal.
-        dense = self._lookup[np.minimum(l1_raw, len(self._lookup) - 1)]
-        if (dense < 0).any():
-            return None
-        result: Dict[Itemset, int] = {
-            (int(r),): int(self._hist[r]) for r in l1_raw}
-        # item_map is sorted, so dense ids inherit l1_raw's ascending order.
-        level = dense.astype(np.int32).reshape(-1, 1)
-        k = 2
-        while level.size and k <= self.max_k:
-            cand = apriori_gen_matrix(level)
-            if cand.size == 0:
-                break
-            tl = self._levels.get(k)
-            if tl is None:
-                return None  # the refresh lattice never reached this depth
-            rows = tl.rows_of(cand)
-            if (rows < 0).any():
-                return None  # candidate born after the refresh: stale
-            counts = tl.counts[rows]
-            keep = counts >= min_count
-            level = cand[keep]
-            for row, c in zip(level, counts[keep]):
-                result[tuple(int(self._item_map[i]) for i in row)] = int(c)
-            k += 1
-        return result
+        with span("repro.svc.walk"):
+            l1_raw = np.nonzero(self._hist >= min_count)[0]
+            # Raw ids outside the refresh item map resolve to -1 via the
+            # lookup's guard slot — a newly frequent item is by itself a
+            # staleness signal.
+            dense = self._lookup[np.minimum(l1_raw, len(self._lookup) - 1)]
+            if (dense < 0).any():
+                return None
+            result: Dict[Itemset, int] = {
+                (int(r),): int(self._hist[r]) for r in l1_raw}
+            # item_map is sorted, so dense ids inherit l1_raw's ascending
+            # order.
+            level = dense.astype(np.int32).reshape(-1, 1)
+            k = 2
+            while level.size and k <= self.max_k:
+                with span("repro.svc.walk.gen", k=k, rows=level.shape[0]):
+                    cand = apriori_gen_matrix(level)
+                if cand.size == 0:
+                    break
+                tl = self._levels.get(k)
+                if tl is None:
+                    # The refresh lattice never reached this depth.
+                    return None
+                rows = tl.rows_of(cand)
+                if (rows < 0).any():
+                    return None  # candidate born after the refresh: stale
+                counts = tl.counts[rows]
+                keep = counts >= min_count
+                level = cand[keep]
+                for row, c in zip(level, counts[keep]):
+                    result[tuple(int(self._item_map[i])
+                                 for i in row)] = int(c)
+                k += 1
+            return result
 
     def _serve_stale(self, min_count: int):
         """The approximate walk: like ``_serve_from_tracked`` but *skips*
@@ -819,15 +845,17 @@ class MiningService:
         lattice."""
         job = self._refresh_job
         if job is not None and job["track_count"] <= min_count:
-            self._pump_refresh(block=True)
+            with span("repro.svc.refresh"):
+                self._pump_refresh(block=True)
             self._drain_deltas()
             served = self._serve_from_tracked(min_count)
             if served is not None:
                 return served
             # Mid-refresh churn outran the ridden lattice: mine fresh below.
         self._abort_refresh()
-        self._start_refresh(min_count)
-        self._pump_refresh(block=True)
+        with span("repro.svc.refresh"):
+            self._start_refresh(min_count)
+            self._pump_refresh(block=True)
         self._drain_deltas()
         served = self._serve_from_tracked(min_count)
         if served is None:  # cannot happen: no churn since the handoff

@@ -1,0 +1,68 @@
+"""The span helper (``repro.core.runtime.spans``) and the phase times it
+fills: every hand-timed boundary of a mine and of the service now takes its
+seconds from its span."""
+
+import time
+
+import numpy as np
+import pytest
+
+from repro.core import FrequentItemsetMiner
+from repro.core.runtime import spans
+from repro.serve import MiningService
+
+
+def _baskets(seed, n, n_items=24, max_len=7):
+    rng = np.random.default_rng(seed)
+    return [sorted(set(rng.integers(0, n_items,
+                                    size=rng.integers(2, max_len)).tolist()))
+            for _ in range(n)]
+
+
+def test_span_times_its_block_with_the_profiler_off():
+    with spans.span("repro.test", rows=3, k=2) as s:
+        time.sleep(0.01)
+    assert 0.01 <= s.seconds < 1.0
+
+
+def test_span_lets_an_exception_through():
+    with pytest.raises(KeyError):
+        with spans.span("repro.test") as s:
+            raise KeyError("x")
+    assert s.seconds >= 0.0
+
+
+FIXED = 7.0  # what every span reports under ``fixed_spans``
+
+
+@pytest.fixture
+def fixed_spans(monkeypatch):
+    exit_ = spans.span.__exit__
+
+    def fixed_exit(self, *exc):
+        exit_(self, *exc)
+        self.seconds = FIXED
+
+    monkeypatch.setattr(spans.span, "__exit__", fixed_exit)
+
+
+def test_job_profiles_take_their_phase_times_from_spans(fixed_spans):
+    res = FrequentItemsetMiner(min_support=0.1, store="perfect_hash").mine(
+        _baskets(0, 300))
+    job1, *levels = res.levels
+    assert job1.seconds == job1.count_seconds == FIXED
+    assert levels
+    for lv in levels:
+        assert lv.encode_seconds == FIXED      # one repro.dispatch
+        assert lv.count_seconds == FIXED       # one repro.wait
+        # repro.gen: the level's generation, or speculation and cut-back.
+        assert lv.gen_seconds in (FIXED, 2 * FIXED)
+
+
+def test_service_reports_take_their_seconds_from_spans(fixed_spans):
+    svc = MiningService(min_support=0.1, store="perfect_hash", n_slots=4,
+                        slot_size=32, eviction="basket")
+    assert svc.ingest(_baskets(1, 128)).seconds == FIXED
+    assert svc.query().seconds == FIXED
+    assert svc.evict(3).seconds == FIXED
+    svc.close()
