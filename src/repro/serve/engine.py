@@ -168,25 +168,64 @@ class _Slot:
 
 class _TrackedLevel:
     """One tracked candidate level: the (C, k) dense-id matrix counted at the
-    last refresh and its delta-maintained exact counts."""
+    last refresh and its delta-maintained exact counts.
 
-    __slots__ = ("cand", "counts", "_index")
+    ``cand`` is lexicographically sorted and duplicate-free (it comes from
+    ``apriori_gen_matrix``, and compaction keeps the order with a mask), so
+    ``rows_of`` looks rows up through a sorted-prefix index, the lattice's
+    trie flattened into arrays: ``_keys[j]`` holds the sorted distinct keys
+    of the length-(j+1) prefixes, where a prefix's key is its parent
+    prefix's rank in ``_keys[j-1]`` times ``_base`` plus its last id, so
+    keys stay below C * ``_base``, far inside int64.  Every row is a
+    distinct full-length prefix, so the rank in the last key array is the
+    row index.
+    """
+
+    __slots__ = ("cand", "counts", "_base", "_keys")
 
     def __init__(self, cand: np.ndarray, counts: np.ndarray) -> None:
         self.cand = np.ascontiguousarray(cand, dtype=np.int32)
         self.counts = np.asarray(counts, dtype=np.int64).copy()
-        self._index: Optional[Dict[bytes, int]] = None
+        self._base = 0
+        self._keys: Optional[List[np.ndarray]] = None
+
+    def _build_index(self) -> None:
+        c, k = self.cand.shape
+        with span("repro.svc.lookup.index", rows=c):
+            self._base = int(self.cand.max()) + 1 if c else 0
+            keys, rank = [], np.zeros((c,), np.int64)
+            for j in range(k):
+                key = rank * self._base + self.cand[:, j]
+                step = np.diff(key)
+                if (step < 0).any() or (j == k - 1 and not step.all()):
+                    raise ValueError(
+                        "tracked level is not lex-sorted and duplicate-free")
+                new = np.empty((c,), bool)
+                new[:1] = True
+                np.not_equal(step, 0, out=new[1:])
+                keys.append(key[new])
+                rank = np.cumsum(new) - 1
+            self._keys = keys
 
     def rows_of(self, queries: np.ndarray) -> np.ndarray:
         """int64[Q] row index per query row; -1 where untracked."""
         with span("repro.svc.lookup", rows=queries.shape[0]):
-            if self._index is None:
-                self._index = {row.tobytes(): i
-                               for i, row in enumerate(self.cand)}
-            q = np.ascontiguousarray(queries, dtype=np.int32)
-            return np.fromiter(
-                (self._index.get(row.tobytes(), -1) for row in q),
-                dtype=np.int64, count=q.shape[0])
+            if self._keys is None:
+                self._build_index()
+            q = np.asarray(queries)
+            if q.shape[0] == 0 or self.cand.shape[0] == 0:
+                return np.full((q.shape[0],), -1, np.int64)
+            found = np.ones((q.shape[0],), bool)
+            rank = np.zeros((q.shape[0],), np.int64)
+            for j, u in enumerate(self._keys):
+                col = q[:, j].astype(np.int64)
+                # An id outside [0, base) would alias another prefix's key.
+                found &= (col >= 0) & (col < self._base)
+                key = rank * self._base + col
+                rank = np.searchsorted(u, key)
+                np.minimum(rank, len(u) - 1, out=rank)
+                found &= u[rank] == key
+            return np.where(found, rank, -1)
 
 
 # One in-flight delta record: every per-level job dispatched for one signed

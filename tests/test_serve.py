@@ -418,6 +418,95 @@ def test_compaction_prunes_drained_rows_and_preserves_parity():
     svc.close()
 
 
+# -- tracked-level lookup ----------------------------------------------------
+def _dict_rows_of(cand, queries):
+    """The lookup ``_TrackedLevel.rows_of`` once made, kept as its
+    reference: a bytes-keyed dict over the level's rows, probed once per
+    query row."""
+    index = {row.tobytes(): i
+             for i, row in enumerate(np.ascontiguousarray(cand, np.int32))}
+    q = np.ascontiguousarray(queries, np.int32)
+    return np.fromiter((index.get(row.tobytes(), -1) for row in q),
+                       dtype=np.int64, count=q.shape[0])
+
+
+def _random_level(rng, k, n_rows=400, n_items=40):
+    rows = np.array([np.sort(rng.choice(n_items, k, replace=False))
+                     for _ in range(n_rows)], np.int32)
+    return np.unique(rows, axis=0)       # lex-sorted and duplicate-free
+
+
+def _compacted_level():
+    """A tracked level as compaction rebuilt it, and the level it was cut
+    from (the compaction scenario above)."""
+    tails = [[3, 4, 5], [4, 5, 6], [3, 5, 6], [3, 4, 6]]
+    svc = MiningService(min_support=0.2, store="perfect_hash", n_slots=1,
+                        slot_size=16, eviction="basket", staleness=2.1,
+                        max_k=5, compact_churn=0.1)
+    svc.ingest([[0, 1, 2] + tails[i % 4] for i in range(16)])
+    svc.query()
+    before = svc._levels[2].cand.copy()
+    svc.ingest([tails[i % 4] for i in range(16)])
+    svc.query()
+    assert svc.compactions >= 1
+    tl = svc._levels[2]
+    svc.close()
+    assert 0 < tl.cand.shape[0] < before.shape[0]
+    return tl, before
+
+
+@pytest.mark.parametrize("case", ["k2", "k3", "k4", "k5", "empty_level",
+                                  "compacted"])
+def test_rows_of_matches_dict_lookup(case):
+    """The sorted-prefix index answers every query row as the dict did:
+    tracked rows in any order, untracked rows, ids at or above the index
+    base (including one built to alias another prefix's key), an empty
+    query, an empty level and a level rebuilt by compaction."""
+    from repro.serve.engine import _TrackedLevel
+
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if case == "compacted":
+        tl, other = _compacted_level()
+        cand = tl.cand
+    else:
+        k = 3 if case == "empty_level" else int(case[1:])
+        other = _random_level(rng, k)
+        cand = (other[:0] if case == "empty_level"
+                else other[rng.random(other.shape[0]) < 0.6])
+        tl = _TrackedLevel(cand, np.arange(cand.shape[0]))
+    k = cand.shape[1]
+    base = int(cand.max()) + 1 if cand.size else 1
+    parts = [other, cand[rng.permutation(cand.shape[0])]]
+    if cand.shape[0]:
+        high = cand[rng.integers(0, cand.shape[0], 8)].copy()
+        high[:, -1] += base
+        parts.append(high)
+        firsts = np.unique(cand[:, 0])
+        if len(firsts) > 1:
+            # (rank(a) - 1) * base + (b + base) == rank(a) * base + b
+            row = cand[np.flatnonzero(cand[:, 0] == firsts[1])[0]].copy()
+            alias = row.copy()
+            alias[0], alias[1] = firsts[0], row[1] + base
+            parts.append(alias[None, :])
+    queries = np.concatenate(parts).astype(np.int32)
+    for q in (queries, queries[:0]):
+        got = tl.rows_of(q)
+        assert got.dtype == np.int64 and got.shape == (q.shape[0],)
+        np.testing.assert_array_equal(got, _dict_rows_of(cand, q))
+    if cand.shape[0]:
+        np.testing.assert_array_equal(tl.rows_of(cand),
+                                      np.arange(cand.shape[0]))
+        assert (tl.rows_of(queries[-1:]) == -1).all()
+
+
+def test_rows_of_refuses_an_unsorted_level():
+    from repro.serve.engine import _TrackedLevel
+
+    tl = _TrackedLevel(np.array([[1, 2], [0, 3]], np.int32), np.zeros(2))
+    with pytest.raises(ValueError):
+        tl.rows_of(np.array([[0, 3]], np.int32))
+
+
 # -- basket stream -----------------------------------------------------------
 def test_basket_stream_seeded_and_reproducible():
     a = list(basket_stream("T10I4D100K", batch_size=32, scale=0.002, seed=4))

@@ -66,3 +66,49 @@ def test_service_reports_take_their_seconds_from_spans(fixed_spans):
     assert svc.query().seconds == FIXED
     assert svc.evict(3).seconds == FIXED
     svc.close()
+
+
+def test_lookup_index_is_built_once_per_tracked_level_per_refresh(
+        monkeypatch):
+    """``repro.svc.lookup.index`` fires once for each tracked level a walk
+    reaches after a refresh, with the level's row count, and never on the
+    queries that follow without one; ``repro.svc.lookup`` keeps its
+    ``rows`` argument."""
+    seen = []
+    init = spans.span.__init__
+
+    def recording_init(self, name, **counts):
+        seen.append((name, counts))
+        init(self, name, **counts)
+
+    monkeypatch.setattr(spans.span, "__init__", recording_init)
+    svc = MiningService(min_support=0.03, store="perfect_hash", n_slots=8,
+                        slot_size=32, staleness=0.5, margin=0.5, max_k=6)
+    svc.ingest(_baskets(2, 224))
+
+    def query(**kw):
+        seen.clear()
+        res = svc.query(**kw)
+        return (res, [c for n, c in seen if n == "repro.svc.lookup.index"],
+                [c for n, c in seen if n == "repro.svc.lookup"])
+
+    def level_rows(n_lookups):
+        return sorted(svc._levels[k].cand.shape[0]
+                      for k in range(2, 2 + n_lookups))
+
+    res, index, lookup = query()
+    assert res.refreshed and len(lookup) >= 2
+    assert all(set(c) == {"rows"} and c["rows"] > 0 for c in lookup)
+    assert sorted(c["rows"] for c in index) == level_rows(len(lookup))
+
+    res, index, lookup_again = query()
+    assert not res.refreshed and index == [] and lookup_again == lookup
+
+    svc.ingest(_baskets(2, 32))    # a slot of baskets already seen: delta path
+    res, index, lookup = query()
+    assert not res.refreshed and index == [] and lookup
+
+    res, index, lookup = query(min_support=0.01)  # below track: refresh
+    assert res.refreshed and res.stale_reason == "below_track"
+    assert sorted(c["rows"] for c in index) == level_rows(len(lookup))
+    svc.close()
