@@ -466,10 +466,15 @@ class LevelLadder:
                 freq_l = np.asarray(jax.device_get(freq_dev[:n_freq]))
                 counts = np.asarray(
                     jax.device_get(counts_dev[:n_freq])).astype(np.int64)
+            eng = self.engine
             prof = JobProfile(
                 k=k1, n_candidates=n_cand, n_frequent=n_freq,
                 seconds=lvl.seconds, count_seconds=lvl.seconds - trim_s,
                 reduce_seconds=trim_s, n_pad=self._n_pad, f_pad=self._f_pad,
+                data_shards=eng.n_data_shards,
+                # The step psums each device's (c_pad,) int32 candidate shard.
+                reduce_bytes=(c_pad // eng.n_cand_shards * 4
+                              if eng.mesh is not None else 0),
             )
             # Translate ladder ids -> miner dense ids at the yield boundary
             # (monotone map: rows stay lex-sorted for the driver/checkpoint).

@@ -174,6 +174,10 @@ class MapReduceEngine:
         # FIFO of (pending, slot, device_counts, n_valid) across all waves.
         self._queue: Deque[tuple] = collections.deque()
         self._job1_jit = {}  # (N, L, n_items) -> compiled histogram job
+        # Bytes of count vector each device has added into a psum over the
+        # data axes (waves and Job1) since construction; a runner reads the
+        # difference across one job's dispatches (JobProfile.reduce_bytes).
+        self.psum_bytes = 0
         # Cross-place() compile caches: re-placing the same engine (repeat
         # mines, benchmark rounds) must not rebuild identical encode/count
         # jits — mesh and store are fixed per engine, so f_pad (encode) and
@@ -189,6 +193,11 @@ class MapReduceEngine:
         if self.mesh is None:
             return 1
         return int(np.prod([self.mesh.shape[a] for a in self.data_axes]))
+
+    def shard_rows(self, n: int) -> int:
+        """Rows each data shard holds of an ``n``-row matrix, padding
+        included (the matrix is padded to a multiple of the shards)."""
+        return -(-n // self.n_data_shards)
 
     @property
     def n_cand_shards(self) -> int:
@@ -218,10 +227,8 @@ class MapReduceEngine:
     def place(self, enc: EncodedDB) -> None:
         """Shard transaction tensors over the data axes; keep them resident."""
         self.abandon()  # handles from a prior DB are void
-        shards = self.n_data_shards
-        n = enc.n_transactions
-        n_padded = ((n + shards - 1) // shards) * shards
-        enc = enc.pad_transactions_to(n_padded)
+        enc = enc.pad_transactions_to(
+            self.shard_rows(enc.n_transactions) * self.n_data_shards)
         trans = self.store.transaction_inputs(enc)
         if self.mesh is not None:
             sharding = NamedSharding(self.mesh, P(self.data_axes))
@@ -343,7 +350,10 @@ class MapReduceEngine:
             if self._count_jit is None:
                 self._count_jit = self._build_count_fn(cands)
                 self._place_jit_cache[ckey] = self._count_jit
-        return self._count_jit(self._trans_device, cands)
+        dev = self._count_jit(self._trans_device, cands)
+        if self.mesh is not None:  # each device psums its candidate shard
+            self.psum_bytes += dev.nbytes // self.n_cand_shards
+        return dev
 
     def _count_encoded(self, pending: "PendingCounts", encoded: Deque) -> None:
         """Move the oldest encode slot into the count FIFO; drain the count
@@ -557,7 +567,8 @@ class MapReduceEngine:
         if n == 0 or n_items == 0:
             return np.zeros((n_items,), np.int64)
         shards = self.n_data_shards
-        n_padded = ((n + shards - 1) // shards) * shards
+        rows = self.shard_rows(n)
+        n_padded = rows * shards
         if n_padded != n:
             pad = np.full((n_padded - n, padded.shape[1]), ITEM_PAD, np.int32)
             padded = np.concatenate([padded, pad])
@@ -569,14 +580,16 @@ class MapReduceEngine:
             return h[:n_items]
 
         key = (padded.shape, n_items)
-        if self.mesh is None:
-            dev = jnp.asarray(padded)
-            if key not in self._job1_jit:
+        with span("repro.job1.put", shards=shards, rows=rows):
+            if self.mesh is None:
+                dev = jnp.asarray(padded)
+            else:
+                dev = jax.device_put(
+                    padded, NamedSharding(self.mesh, P(self.data_axes)))
+        if key not in self._job1_jit:
+            if self.mesh is None:
                 self._job1_jit[key] = jax.jit(hist_local)
-        else:
-            sharding = NamedSharding(self.mesh, P(self.data_axes))
-            dev = jax.device_put(padded, sharding)
-            if key not in self._job1_jit:
+            else:
                 def sharded(p):
                     return jax.lax.psum(hist_local(p), self.data_axes)
 
@@ -584,6 +597,8 @@ class MapReduceEngine:
                     sharded, mesh=self.mesh,
                     in_specs=(P(self.data_axes),), out_specs=P()))
         hist = self._job1_jit[key](dev)
+        if self.mesh is not None:  # each device psums a whole histogram
+            self.psum_bytes += hist.nbytes
         return fetch_global(hist).astype(np.int64)
 
     @staticmethod

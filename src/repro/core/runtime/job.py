@@ -43,6 +43,13 @@ re-tunes (``inflight=None`` re-samples the depth when a wave's *per-chunk*
 (C, k) work — min(C, cand_block) * k — drifts more than 2x from the shape
 it was tuned on); 0 when auto-sizing is off or no wave ever drifted.
 
+``data_shards`` and ``reduce_bytes`` record what a ``data`` mesh adds to a
+job: the number of transaction shards (mappers) it counted over, and the
+bytes of partial-count vector each device contributed to the job's
+``lax.psum`` reductions (the shuffle + reducer), summed over its chunks —
+the padded chunk length x 4 for a count wave (a device's candidate shard
+under ``cand`` sharding), n_items x 4 for Job1.  One device: 1 and 0.
+
 Fault-tolerance telemetry (all zero on a clean run with recovery enabled —
 the fields record what the recovery layer *did*, not what it cost):
 ``retries`` counts failed task attempts that were re-run (crashes and
@@ -86,6 +93,12 @@ class JobProfile:
     # Out-of-core telemetry: transaction chunks the job streamed through
     # (ChunkedDatasetReader ingestion); 0 on the resident-DB paths.
     chunks: int = 0
+    # Data-mesh telemetry: the ``data`` shards the job's transactions were
+    # split over (1 on one device), and the bytes of count vector each
+    # device added into the job's psums, summed over its dispatches (0 on
+    # one device, where no psum runs).
+    data_shards: int = 1
+    reduce_bytes: int = 0
 
     @property
     def parallel_seconds(self) -> float:

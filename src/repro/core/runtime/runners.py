@@ -685,11 +685,12 @@ class _JaxPending:
     """Async-job handle: blocks on the engine FIFO, then fills the profile."""
 
     def __init__(self, runner: "JaxRunner", job: CountJob, pending,
-                 encode_s: float) -> None:
+                 encode_s: float, reduce_bytes: int) -> None:
         self._runner = runner
         self._job = job
         self._pending = pending
         self._encode_s = encode_s
+        self._reduce_bytes = reduce_bytes
 
     def poll(self) -> bool:
         """Non-blocking: drain whatever the device has finished, report
@@ -706,6 +707,8 @@ class _JaxPending:
             encode_seconds=self._encode_s, count_seconds=wait_s,
             inflight_depth=self._runner.engine.inflight,
             inflight_retunes=self._runner.engine.inflight_retunes,
+            data_shards=self._runner.engine.n_data_shards,
+            reduce_bytes=self._reduce_bytes,
         )
         return counts, prof
 
@@ -784,6 +787,7 @@ class JaxRunner(BaseRunner):
 
     def job1(self) -> Tuple[np.ndarray, JobProfile]:
         n_chunks = 0
+        psum_bytes = self.engine.psum_bytes
         with span("repro.job1") as s:
             if self._reader is not None:
                 # Per-chunk device histograms, summed on host: bincount is
@@ -800,7 +804,9 @@ class JaxRunner(BaseRunner):
         # semantic as SimRunner, keeping k=1 rows comparable across backends.
         prof = JobProfile(k=1, n_candidates=int(np.count_nonzero(hist)),
                           seconds=s.seconds, count_seconds=s.seconds,
-                          chunks=n_chunks)
+                          chunks=n_chunks,
+                          data_shards=self.engine.n_data_shards,
+                          reduce_bytes=self.engine.psum_bytes - psum_bytes)
         return hist, prof
 
     def place(self, item_map: np.ndarray) -> None:
@@ -829,7 +835,8 @@ class JaxRunner(BaseRunner):
                        dataset_digest(item_arr))
             enc = DATASET_CACHE.get_or_build(
                 key, lambda: self._encode(item_arr))
-            with span("repro.place.put"):
+            with span("repro.place.put", shards=self.engine.n_data_shards,
+                      rows=self.engine.shard_rows(enc.n_transactions)):
                 self.engine.place(enc)
 
     def _encode(self, item_map: np.ndarray):
@@ -897,9 +904,11 @@ class JaxRunner(BaseRunner):
                 raise DeviceLostError(lost=spec.lost, k=job.k)
         if self._reader is not None:
             return self._count_chunked_async(job)
+        psum_bytes = self.engine.psum_bytes
         with span("repro.dispatch", k=job.k, rows=job.n_candidates) as d:
             pending = self.engine.count_candidates_async(job.cand)
-        return _JaxPending(self, job, pending, d.seconds)
+        return _JaxPending(self, job, pending, d.seconds,
+                           self.engine.psum_bytes - psum_bytes)
 
     def _count_chunked_async(self, job: CountJob) -> _ChunkedPending:
         """Stream the reader through the wave: per chunk, the serving path's

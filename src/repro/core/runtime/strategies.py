@@ -167,6 +167,7 @@ def _combined(runner, level, min_count, start_k, max_k, should_extend):
         frequent: Dict[Itemset, int] = {}
         encode_s = count_s = reduce_s = build_s = runner_gen_s = 0.0
         inflight_depth = inflight_retunes = 0
+        data_shards, reduce_bytes = 1, 0
         retries = spec_launches = spec_wins = 0
         backoff_s = 0.0
         mappers: List[float] = []
@@ -183,6 +184,8 @@ def _combined(runner, level, min_count, start_k, max_k, should_extend):
             spec_launches += prof.speculative_launches
             spec_wins += prof.speculative_wins
             backoff_s += prof.backoff_seconds
+            data_shards = max(data_shards, prof.data_shards)
+            reduce_bytes += prof.reduce_bytes
             inflight_depth = max(inflight_depth, prof.inflight_depth)
             # Cumulative engine counter: the latest wave carries the total.
             inflight_retunes = max(inflight_retunes, prof.inflight_retunes)
@@ -203,7 +206,8 @@ def _combined(runner, level, min_count, start_k, max_k, should_extend):
             mapper_seconds=mappers, inflight_depth=inflight_depth,
             inflight_retunes=inflight_retunes, retries=retries,
             speculative_launches=spec_launches, speculative_wins=spec_wins,
-            backoff_seconds=backoff_s,
+            backoff_seconds=backoff_s, data_shards=data_shards,
+            reduce_bytes=reduce_bytes,
         )
         yield stats, frequent
         top_k = max((len(s) for s in frequent), default=0)

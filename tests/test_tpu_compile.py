@@ -155,3 +155,32 @@ def _ladder_k2_step(eng, data_sharding, replicated):
     scalar = _shape(replicated, (), jnp.int32)
     return step.lower(trans, _shape(replicated, (l_pad, 1), jnp.int32),
                       scalar, scalar).compile()
+
+
+def test_data_mesh_count_step_compiles_for_v5e_2x2(topo):
+    """The four-chip cell's host-loop chunk: T10I4D400K on a 1-D ``data``
+    mesh of the four described chips, each holding 100,000 baskets, one
+    32,768-pair chunk counted on each and its partial counts psum'd."""
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.core.runtime.engine import MapReduceEngine
+
+    mesh = Mesh(np.array(topo.devices), ("data",),
+                axis_types=(AxisType.Auto,))
+    eng = MapReduceEngine(mesh=mesh)
+    rows = NamedSharding(mesh, PartitionSpec("data"))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    trans = jax.eval_shape(eng.store.device_transaction_inputs,
+                           jax.ShapeDtypeStruct((4 * N, WIDTH), jnp.int32),
+                           jax.ShapeDtypeStruct((4 * N, F_PAD), jnp.uint8))
+    eng._trans_device = {k: _shape(rows, v.shape, v.dtype)
+                         for k, v in trans.items()}
+    cands = jax.eval_shape(
+        functools.partial(eng.store.encode_candidates, f_pad=F_PAD),
+        jax.ShapeDtypeStruct((CHUNK, 2), jnp.int32))
+    cands = {k: _shape(replicated, v.shape, v.dtype)
+             for k, v in cands.items()}
+    compiled = eng._build_count_fn(cands).lower(eng._trans_device,
+                                                cands).compile()
+    assert "all-reduce" in compiled.as_text()  # the psum over ``data``
+    assert _total_bytes(compiled) < HBM_BYTES
