@@ -166,16 +166,27 @@ class FrequentItemsetMiner:
             min_count = max(1, int(np.ceil(self.min_support * n)))
             runner = self._make_runner()
             restarts = 0
-            while True:
-                self.active_runner = runner  # introspection: tests/benchmarks
-                try:
-                    return self._mine_once(runner, transactions, n, min_count)
-                except DeviceLostError as err:
-                    restarts += 1
-                    runner.close(wait=False)
-                    if restarts > self.elastic_restarts:
-                        raise
-                    runner = self._elastic_rebuild(runner, err)
+            done = False
+            try:
+                while True:
+                    # introspection: tests/benchmarks
+                    self.active_runner = runner
+                    try:
+                        res = self._mine_once(runner, transactions, n,
+                                              min_count)
+                        done = True
+                        return res
+                    except DeviceLostError as err:
+                        restarts += 1
+                        runner.close(wait=False)
+                        if restarts > self.elastic_restarts:
+                            raise
+                        runner = self._elastic_rebuild(runner, err)
+            finally:
+                # A runner built for this mine is closed with it: its device
+                # DB is freed now, not at the next cyclic collection.
+                if runner is not self.runner:
+                    runner.close(wait=done)
 
     def _elastic_rebuild(self, runner: BaseRunner,
                          err: DeviceLostError) -> BaseRunner:

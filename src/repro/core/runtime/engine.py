@@ -224,6 +224,15 @@ class MapReduceEngine:
             pending._cancelled = True
         self._queue.clear()
 
+    def release(self) -> None:
+        """Drop the placed DB, its device tensors and host encoding, with
+        every outstanding handle.  The engine's jits hold its bound methods,
+        so an engine dropped with its DB in place keeps that device memory
+        until the next cyclic collection."""
+        self.abandon()
+        self._trans_device = None
+        self._enc = None
+
     def place(self, enc: EncodedDB) -> None:
         """Shard transaction tensors over the data axes; keep them resident."""
         self.abandon()  # handles from a prior DB are void

@@ -84,7 +84,7 @@ from repro.core.runtime.faults import (
 from repro.core.runtime.job import CountJob, JobProfile
 from repro.core.runtime.spans import span
 from repro.core.sequential import SEQUENTIAL_STORES
-from repro.core.stores import encode_db_from_padded, padded_from_transactions
+from repro.core.stores import encode_db_from_padded, pack_transactions
 from repro.core.stores.base import EncodedDB, dense_remap_padded
 
 
@@ -761,10 +761,11 @@ class JaxRunner(BaseRunner):
         return f"{self.kind}/{self.engine.store_name}"
 
     def close(self, wait: bool = True) -> None:
-        """Abandon the engine's outstanding dispatch queue (chunk results
-        still in flight hold device buffers; an elastic restart must drop
-        them before the replacement mesh is built)."""
-        self.engine.abandon()
+        """Release the engine's placed DB and abandon its outstanding
+        dispatch queue (chunk results still in flight hold device buffers;
+        an elastic restart must drop them before the replacement mesh is
+        built)."""
+        self.engine.release()
 
     def ingest(self, transactions: Sequence[Sequence[int]]) -> None:
         reader = _as_reader(transactions)
@@ -779,10 +780,14 @@ class JaxRunner(BaseRunner):
             return
         # The single host pass over the raw lists; everything downstream
         # (Job1, dense re-encode, counting) is vectorized or on device.
+        # ``repro.ingest.pack`` carries the ids flattened and the duplicate
+        # ids dropped.
         self._reader = None
         with span("repro.ingest", rows=len(transactions)):
-            self._padded_raw, self._n_raw = padded_from_transactions(
-                transactions)
+            with span("repro.ingest.pack") as s:
+                self._padded_raw, self._n_raw, items, dups = (
+                    pack_transactions(transactions))
+                s.note(items=items, dups=dups)
         self._raw_digest = None  # lazily computed on first place()
 
     def job1(self) -> Tuple[np.ndarray, JobProfile]:

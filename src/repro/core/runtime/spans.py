@@ -5,10 +5,12 @@ cycle.  It enters a ``jax.profiler.TraceAnnotation``, so under a profiler
 trace the span sits on the host plane, on the same clock as the device
 planes, with ``counts`` as its event arguments; and it reads
 ``perf_counter`` around the same stretch, so the caller takes its phase
-time from ``.seconds`` after the block.  There is no switch and no log:
-with the profiler off a span costs the annotation's enter and exit and two
-clock reads.  Names carry the prefix ``repro.``.  A span is host code: none
-may sit inside a jitted function, where it would run only while tracing.
+time from ``.seconds`` after the block; counts known only once the work
+is done join the arguments through ``.note(**counts)`` inside the block.
+There is no switch and no log: with the profiler off a span costs the
+annotation's enter and exit and two clock reads.  Names carry the prefix
+``repro.``.  A span is host code: none may sit inside a jitted function,
+where it would run only while tracing.
 """
 
 from __future__ import annotations
@@ -27,6 +29,10 @@ class span:
     def __init__(self, name: str, **counts: int) -> None:
         self._note = TraceAnnotation(name, **counts)
         self.seconds = 0.0
+
+    def note(self, **counts: int) -> None:
+        """Add ``counts`` to the span's event arguments (inside the block)."""
+        self._note.set_metadata(**counts)
 
     def __enter__(self) -> "span":
         self._note.__enter__()

@@ -22,7 +22,7 @@ the only per-wave host-to-device transfer.
 
 from repro.core.stores.base import (
     DeltaCountMixin, EncodedDB, dense_remap_padded, encode_db,
-    encode_db_from_padded, pack_bitmap, pad_candidates,
+    encode_db_from_padded, pack_bitmap, pack_transactions, pad_candidates,
     padded_from_transactions, ITEM_PAD, WORD_BITS,
 )
 from repro.core.stores.perfect_hash import PerfectHashStore
@@ -45,6 +45,7 @@ __all__ = [
     "dense_remap_padded",
     "encode_db",
     "encode_db_from_padded",
+    "pack_transactions",
     "padded_from_transactions",
     "pack_bitmap",
     "pad_candidates",

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Sequence
 
 import numpy as np
@@ -81,23 +82,68 @@ class EncodedDB:
         return out
 
 
+def pack_transactions(
+    transactions: Sequence[Sequence[int]], min_len: int = 8
+) -> tuple:
+    """``padded_from_transactions`` with its two counts: returns
+    ``(padded, n_raw, items, dups)``, where ``items`` is the number of ids
+    the rows hold and ``dups`` how many of them repeat an id of their row.
+
+    A fixed number of NumPy passes, none per basket: the rows are flattened
+    once, each id keyed ``row * base + (id - lo)`` (``base`` spans the ids,
+    so the keys order by row, then by id, and each row keeps its segment),
+    the keys sorted once, a repeat of its predecessor dropped (the
+    per-basket dedupe), and the rest laid into the first slots of their
+    rows.  A row without ``len`` (a generator) is materialised first.
+    """
+    n = len(transactions)
+    try:
+        lens = np.fromiter(map(len, transactions), np.int64, count=n)
+    except TypeError:
+        return pack_transactions([list(t) for t in transactions], min_len)
+    items = int(lens.sum())
+    flat = np.fromiter(itertools.chain.from_iterable(transactions), np.int64,
+                       count=items)
+    if not items:
+        width = max(min_len, 0 if n else 1)
+        return np.full((n, width), ITEM_PAD, np.int32), 0, 0, 0
+    lo, hi = int(flat.min()), int(flat.max())
+    base = hi - lo + 1
+    # int32 keys sort faster; they are exact while every key and id fits.
+    fits = n * base < 2**31 and -2**31 <= lo and hi < 2**31
+    kdt = np.int32 if fits else np.int64
+    row_key = np.repeat(np.arange(n, dtype=kdt) * kdt(base) - kdt(lo), lens)
+    key = row_key + flat.astype(kdt, copy=False)
+    key.sort()
+    first = np.empty(items, bool)
+    first[0] = True
+    np.not_equal(key[1:], key[:-1], out=first[1:])
+    key -= row_key               # each row's ids, sorted
+    ulen = lens
+    dups = items - int(np.count_nonzero(first))
+    if dups:
+        at = np.flatnonzero(first)
+        key = key[at]
+        ulen = np.diff(np.searchsorted(at, np.cumsum(lens)), prepend=0)
+    width = max(min_len, int(ulen.max()))
+    padded = np.full((n, width), ITEM_PAD, np.int32)
+    # A boolean mask fills in row-major order: row r's first ulen[r] slots.
+    padded[np.arange(width) < ulen[:, None]] = key
+    return padded, hi + 1, items, dups
+
+
 def padded_from_transactions(
     transactions: Sequence[Sequence[int]], min_len: int = 8
 ) -> tuple:
     """One host pass over raw transaction lists -> ((N, L) int32 padded
     matrix of unique sorted ids, ITEM_PAD-padded; max item id + 1).
 
-    This is the single per-transaction Python loop of the ingestion path —
-    Job1 and the dense re-encode both derive from the returned matrix with
-    vectorized (or on-device) operations.
+    L is the longest row's count of unique ids, at least ``min_len`` (1 for
+    zero rows).  This is the ingestion path's one pass over the baskets
+    (``pack_transactions``): Job1 and the dense re-encode both derive from
+    the returned matrix with vectorized (or on-device) operations.
     """
-    n = len(transactions)
-    rows = [sorted(set(int(x) for x in t)) for t in transactions]
-    lmax = max(min_len, max((len(r) for r in rows), default=1))
-    padded = np.full((n, lmax), ITEM_PAD, dtype=np.int32)
-    for i, r in enumerate(rows):
-        padded[i, : len(r)] = r
-    n_raw = max((r[-1] for r in rows if r), default=-1) + 1
+    padded, n_raw, _, _ = pack_transactions(transactions, min_len)
     return padded, n_raw
 
 

@@ -231,6 +231,33 @@ def test_place_cancels_outstanding_pendings(t10_db):
         pending.result()
 
 
+@pytest.mark.parametrize("device_loop", [False, True])
+def test_mine_frees_its_device_db_without_the_cyclic_collector(
+        t10_db, oracle, device_loop):
+    """A runner the miner builds is closed with the mine, so its placed DB
+    leaves the device at once; a runner passed in stays open."""
+    import gc
+
+    FrequentItemsetMiner(min_support=MIN_SUPPORT,
+                         device_loop=device_loop).mine(t10_db)  # warm-up
+    gc.collect()
+    before = len(jax.live_arrays())
+    gc.disable()
+    try:
+        for _ in range(2):
+            res = FrequentItemsetMiner(min_support=MIN_SUPPORT,
+                                       device_loop=device_loop).mine(t10_db)
+            assert res.itemsets == oracle
+            assert len(jax.live_arrays()) == before
+    finally:
+        gc.enable()
+    runner = JaxRunner()
+    FrequentItemsetMiner(min_support=MIN_SUPPORT, runner=runner).mine(t10_db)
+    res = FrequentItemsetMiner(min_support=MIN_SUPPORT,
+                               runner=runner).mine(t10_db)
+    assert res.itemsets == oracle and runner.engine._enc is not None
+
+
 def test_miner_rejects_runner_plus_backend_config():
     with pytest.raises(ValueError, match="not both"):
         FrequentItemsetMiner(store="bitmap", runner=JaxRunner())

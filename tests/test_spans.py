@@ -112,3 +112,32 @@ def test_lookup_index_is_built_once_per_tracked_level_per_refresh(
     assert res.refreshed and res.stale_reason == "below_track"
     assert sorted(c["rows"] for c in index) == level_rows(len(lookup))
     svc.close()
+
+
+def test_ingest_span_counts_rows_items_and_dups(tmp_path):
+    """Traced on the CPU, a mine's ``repro.ingest`` carries ``rows`` and its
+    ``repro.ingest.pack`` the ids flattened (``items``) and the repeats of
+    an id within a basket dropped (``dups``)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    db = [row + row[:2] for row in _baskets(3, 200)]  # two ids repeated
+    items = sum(len(row) for row in db)
+    dups = sum(len(row) - len(set(row)) for row in db)
+    assert dups > 0
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        FrequentItemsetMiner(min_support=0.1, store="perfect_hash").mine(db)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    args = {ev.name: dict(ev.stats)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("repro.ingest")}
+    assert args["repro.ingest"] == {"rows": 200}
+    assert args["repro.ingest.pack"] == {"items": items, "dups": dups}
