@@ -3,7 +3,7 @@
 PROVENANCE: this example (and ``repro.models``/``repro.train``/
 ``repro.configs``) is inherited scaffolding from the repo seed, not part of
 the Apriori reproduction — the paper track is ``quickstart.py`` /
-``mine_t10.py`` / ``benchmarks/``.  It still runs, but is gated behind
+``mine_t10.py``.  It still runs, but is gated behind
 ``REPRO_LM=1`` so nobody mistakes it for the supported surface.
 
   REPRO_LM=1 PYTHONPATH=src python examples/train_lm.py --steps 30

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Markdown link checker (stdlib only, offline).
 
-    python scripts/check_links.py README.md docs/ARCHITECTURE.md EXPERIMENTS.md
+    python scripts/check_links.py README.md docs/ARCHITECTURE.md ROADMAP.md
 
 For every ``[text](target)`` and bare ``<path>``-style reference in the given
 markdown files, verifies that

@@ -7,15 +7,15 @@ generation + structure build + chunk counting (Algorithm 3), per-mapper
 combiner, then the global reducer — on CPU, with per-phase wall-clock
 measurement. Mappers are *executed sequentially but timed individually*; the
 reported parallel time of an iteration is ``max(mapper times) + reduce time``,
-which is what an N-slot Hadoop cluster would see (this container has one core,
-so true concurrency is simulated; recorded in EXPERIMENTS.md). The saturation
-the paper observes (Fig 5) emerges mechanically: every mapper re-runs
-apriori-gen and rebuilds C_k, a fixed cost that parallelism cannot shrink.
+which is what an N-slot Hadoop cluster would see (the mappers run one after
+another, so the concurrency is modelled). The saturation the paper observes
+(Fig 5) emerges mechanically: every mapper re-runs apriori-gen and rebuilds
+C_k, a fixed cost that parallelism cannot shrink.
 
 ``run_mapreduce_apriori`` drives ``SimRunner`` through the same
 ``FrequentItemsetMiner`` level loop (SPC strategy) as the JAX backends, so
 both tracks emit the same per-job ``JobProfile`` rows and can be compared
-head-to-head in ``benchmarks/``.
+head-to-head.
 """
 
 from __future__ import annotations

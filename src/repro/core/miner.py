@@ -169,7 +169,7 @@ class FrequentItemsetMiner:
             done = False
             try:
                 while True:
-                    # introspection: tests/benchmarks
+                    # introspection: tests
                     self.active_runner = runner
                     try:
                         res = self._mine_once(runner, transactions, n,

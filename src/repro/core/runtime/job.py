@@ -3,8 +3,8 @@
 ``JobProfile`` replaces both of the repo's historical profiling dataclasses
 (``hadoop_sim.IterationProfile`` and ``miner.LevelStats``): every runner —
 the Hadoop cost-model simulator and the JAX backends alike — reports one row
-per counting job through this schema, so ``benchmarks/`` can put the
-Java-equivalent and array-store paths side by side in one table.
+per counting job through this schema, so the Java-equivalent and
+array-store paths can be put side by side in one table.
 
 Phase fields (seconds; a backend leaves phases it does not have at 0.0):
 

@@ -4,20 +4,19 @@ per-cell record.
 
 The paper's contribution is a *grid* — candidate structure x dataset x
 min_support x mapper count — and its follow-ups re-run the same grid on new
-runtimes.  ``benchmarks/bench_paper.py`` drives that grid; this module owns
-the backend-agnostic cell mechanics so any driver (benchmarks, tests, ad-hoc
-scripts) gets the same guarantees:
+runtimes.  ``tests/test_paper_grid.py`` runs that grid as a parity test;
+this module owns the backend-agnostic cell mechanics so any driver (tests,
+ad-hoc scripts) gets the same guarantees:
 
 ``aggregate_profiles``
     Collapse a mining run's ``JobProfile`` list into one flat dict (total and
     per-phase seconds, the paper's ``parallel_seconds`` cluster model,
-    candidate totals, pipeline depth stats) — the cell payload persisted in
-    ``BENCH_paper.json``.
+    candidate totals, pipeline depth stats) — the per-cell record.
 
 ``itemset_digest``
     Canonical sha256 over the sorted ``(itemset, support)`` pairs.  Two
-    backends agree on a cell iff their digests match — recording the digest
-    per cell makes cross-backend identity auditable from the JSON alone.
+    backends agree on a cell iff their digests match, so a cell's identity
+    can be recorded and compared as one short string.
 
 ``run_parity_cell``
     Mine the same database with every backend in a cell, hard-assert that

@@ -35,7 +35,7 @@ def candidates_to_khot(cand: np.ndarray, f_pad: int) -> tuple[np.ndarray, np.nda
 
 class BitmapMXUStore(DeltaCountMixin):
     name = "bitmap"
-    use_kernel = False  # flipped by engine/benchmarks to run the Pallas kernel
+    use_kernel = False  # class switch: True counts with the Pallas kernel
 
     @staticmethod
     def transaction_inputs(enc: EncodedDB) -> dict:

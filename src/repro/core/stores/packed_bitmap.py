@@ -41,7 +41,7 @@ def pack_candidates_device(cand: jnp.ndarray, n_words: int) -> jnp.ndarray:
 
 class PackedBitmapStore(DeltaCountMixin):
     name = "packed_bitmap"
-    use_kernel = False  # flipped by engine/benchmarks to run the Pallas kernel
+    use_kernel = False  # class switch: True counts with the Pallas kernel
 
     @staticmethod
     def transaction_inputs(enc: EncodedDB) -> dict:

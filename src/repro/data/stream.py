@@ -7,7 +7,7 @@ than a monolithic DB.  ``basket_stream`` replays any registered dataset
 arrival order (optionally shuffled), batch sizes are drawn around a target
 rate, and each basket carries a monotonically increasing timestamp.  Seeded
 end to end, so a stream is exactly reproducible — the property the
-serving parity tests and ``BENCH_serve`` both lean on.
+serving parity tests lean on.
 
 Determinism is keyed **per arrival, not per draw**: each epoch derives
 three independent RNG streams from ``SeedSequence([seed, tag, epoch,

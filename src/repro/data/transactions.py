@@ -4,9 +4,8 @@ The paper evaluates on T10I4D100K (IBM Quest synthetic) and the two KDD-Cup-2000
 click-stream sets BMS_WebView_1/2. The real BMS files are not redistributable
 offline, so :func:`bms_webview_twin` generates statistical twins matched on
 transaction count, item count and mean transaction length (Zipf item popularity,
-geometric-ish lengths) — recorded in EXPERIMENTS.md. :func:`quest_generator` is
-a faithful simplification of the IBM Quest procedure (weighted patterns,
-corruption, Poisson lengths).
+geometric-ish lengths). :func:`quest_generator` is a faithful simplification
+of the IBM Quest procedure (weighted patterns, corruption, Poisson lengths).
 """
 
 from __future__ import annotations

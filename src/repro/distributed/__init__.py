@@ -6,12 +6,6 @@ from repro.distributed.ctx import (
     process_count,
     process_index,
 )
-from repro.distributed.sharding import (
-    ShardingRules,
-    default_rules,
-    batch_pspec,
-    act_pspec,
-)
 
 __all__ = [
     "MultihostSpec",
@@ -20,8 +14,4 @@ __all__ = [
     "multihost_env",
     "process_count",
     "process_index",
-    "ShardingRules",
-    "default_rules",
-    "batch_pspec",
-    "act_pspec",
 ]

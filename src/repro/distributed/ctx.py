@@ -1,45 +1,31 @@
-"""Distributed context: multi-host initialization + activation sharding.
+"""Multi-host initialization and the matching device->host fetch.
 
-Two concerns live here, both "ambient state a launcher establishes before
-model/runtime code runs":
-
-**Multi-host initialization** (the maxtext launch idiom: the same binary on
-every host, its role decided entirely by environment variables).  A launcher
-exports ``REPRO_COORDINATOR`` / ``REPRO_NUM_PROCESSES`` /
-``REPRO_PROCESS_ID`` and every process calls :func:`initialize_multihost`
-before touching jax device state; with the trio unset it is a no-op, so the
-single-process path is byte-for-byte unchanged.  After initialization
-``jax.device_count()`` is *global* (processes x local devices), so the
-existing mesh builders (``launch.mesh.make_data_mesh`` /
-``make_data_cand_mesh``) span processes with no changes — the counting
-engine's shard_map psum becomes a real cross-process collective.  On the CPU
-backend cross-process collectives need the gloo implementation, which must
-be selected before ``jax.distributed.initialize`` — that ordering is exactly
-why this is one idempotent entry point instead of launcher boilerplate.
+The maxtext launch idiom: the same binary on every host, its role decided
+entirely by environment variables.  A launcher exports
+``REPRO_COORDINATOR`` / ``REPRO_NUM_PROCESSES`` / ``REPRO_PROCESS_ID`` and
+every process calls :func:`initialize_multihost` before touching jax device
+state; with the trio unset it is a no-op, so the single-process path is
+byte-for-byte unchanged.  After initialization ``jax.device_count()`` is
+*global* (processes x local devices), so the existing mesh builders
+(``launch.mesh.make_data_mesh`` / ``make_data_cand_mesh``) span processes
+with no changes — the counting engine's shard_map psum becomes a real
+cross-process collective.  On the CPU backend cross-process collectives need
+the gloo implementation, which must be selected before
+``jax.distributed.initialize`` — that ordering is exactly why this is one
+idempotent entry point instead of launcher boilerplate.
 :func:`fetch_global` is the matching device->host fetch: fully-addressable
 or fully-replicated arrays (every engine output on the data-sharded path)
 fetch directly, anything else goes through ``process_allgather``.
-
-**Activation-sharding context.**  Model code is mesh-agnostic; launchers
-activate (mesh, rules) here and the layers call :func:`constrain` on
-intermediate activations. Without an active context, constrain is a no-op
-(single-device tests). This is the GSPMD discipline that keeps the
-partitioner from replicating intermediates inside remat'd scan bodies
-(observed: an unconstrained forward attention-score dot materialized the
-full global batch per device — 17x FLOP inflation).
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
-from contextvars import ContextVar
-from typing import Mapping, Optional, Tuple
+from typing import Mapping, Optional
 
 import numpy as np
 import jax
-from jax.sharding import NamedSharding
 
 # -- multi-host initialization (env-driven, the maxtext launch idiom) --------
 
@@ -157,29 +143,3 @@ def fetch_global(x) -> np.ndarray:
 
     return np.asarray(multihost_utils.process_allgather(x, tiled=True))
 
-
-# -- activation-sharding context ---------------------------------------------
-
-_ACTIVE: ContextVar[Optional[Tuple[object, object]]] = ContextVar(
-    "repro_sharding_ctx", default=None)
-
-
-@contextlib.contextmanager
-def use_sharding(mesh, rules):
-    token = _ACTIVE.set((mesh, rules))
-    try:
-        yield
-    finally:
-        _ACTIVE.reset(token)
-
-
-def constrain(x, *axes):
-    """Constrain ``x``'s sharding by logical axis names (None = replicated)."""
-    active = _ACTIVE.get()
-    if active is None:
-        return x
-    mesh, rules = active
-    from repro.models.params import logical_to_pspec
-
-    pspec = logical_to_pspec(axes, rules.rules, x.shape, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))

@@ -1,24 +1,17 @@
-"""Elastic restart: resume a run on a different device count.
+"""Elastic restart of a mine: rebuild the counting mesh on the survivors.
 
-When a pod (or any device subset) is lost, a production job restarts on the
-surviving topology. Checkpoints here store *global* tensors (see
-checkpoint.py), so elasticity reduces to: build the largest usable mesh from
-the surviving devices, re-derive shardings from the same logical rules, and
-restore. ``elastic_mesh`` picks the new mesh shape; ``resume`` does the whole
-dance. Exercised in tests by shrinking a fake-device mesh between save and
-restore.
+When devices are lost mid-mine, ``FrequentItemsetMiner`` rebuilds the
+largest usable ``data`` (or ``data x cand``) mesh from the devices left
+(:func:`surviving_devices`, :func:`elastic_data_cand_mesh`), restores its
+level checkpoint and resumes; counts are identical on every mesh shape.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import numpy as np
 import jax
-
-from repro.distributed import checkpoint as ckpt
-from repro.distributed.sharding import ShardingRules, default_rules
-from repro.models.params import shardings as mk_shardings
 
 
 def surviving_devices(mesh, lost: int) -> list:
@@ -55,44 +48,3 @@ def elastic_data_cand_mesh(devices: Sequence, want_cand: bool = False):
     usable = n_data * n_cand
     grid = np.asarray(devices[:usable]).reshape(n_data, n_cand)
     return jax.sharding.Mesh(grid, ("data", "cand"))
-
-
-def elastic_mesh(
-    devices: Optional[Sequence] = None,
-    model_axis: int = 16,
-    axis_names: Tuple[str, str] = ("data", "model"),
-):
-    """Largest (data, model) mesh on the surviving devices.
-
-    Keeps the model axis at ``model_axis`` if possible (TP degree is baked
-    into compiled kernels' efficiency, so prefer shedding data parallelism);
-    otherwise falls back to the largest power-of-two split.
-    """
-    devices = list(devices if devices is not None else jax.devices())
-    n = len(devices)
-    model = model_axis
-    while model > 1 and n % model:
-        model //= 2
-    data = n // model
-    usable = data * model
-    mesh_devices = np.asarray(devices[:usable]).reshape(data, model)
-    return jax.sharding.Mesh(mesh_devices, axis_names)
-
-
-def resume(ckpt_dir: str, abstract_state, mesh=None, rules: ShardingRules = None,
-           step: Optional[int] = None):
-    """Restore ``abstract_state`` (tree of ParamSpec) onto ``mesh``.
-
-    Returns (state_tree, step, extra) with every tensor device_put with the
-    sharding the current mesh dictates — regardless of the mesh that saved it.
-    """
-    mesh = mesh if mesh is not None else elastic_mesh()
-    rules = rules or default_rules()
-    sh = mk_shardings(abstract_state, mesh, rules.rules)
-    from repro.models.params import shape_structs
-
-    like = shape_structs(abstract_state)
-    out = ckpt.restore(ckpt_dir, like, step=step, shardings=sh)
-    if out is None:
-        raise FileNotFoundError(f"no checkpoint in {ckpt_dir}")
-    return out

@@ -19,16 +19,26 @@ Logical names used by the model code:
   seq       sequence dim of activations     -> None (context-parallel opt-in)
   cache_seq KV-cache sequence dim           -> None
   layers    scan/stack dim                  -> None
+
+Also here, on the LM side: the activation-sharding context the layers
+constrain their intermediates under, and elastic restart of a parameter
+tree onto a (data, model) mesh of the surviving devices.  The miner's own
+elastic rebuild (``distributed.elastic``) needs none of this.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Optional, Tuple
+from contextvars import ContextVar
+from typing import Optional, Sequence, Tuple
 
-from jax.sharding import PartitionSpec as P
+import numpy as np
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models.params import logical_to_pspec
+from repro.distributed import checkpoint as ckpt
+from repro.models.params import logical_to_pspec, shape_structs, shardings
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,7 +68,7 @@ def default_rules(multi_pod: bool = False, *, fsdp: bool = True,
         # axes makes GSPMD all-gather the (G,S,E,C) dispatch tensor — 19 TB —
         # because the einsum contracts the data-sharded token dim against a
         # data-sharded expert dim. Full-mesh EP needs an explicit shard_map
-        # all-to-all; see EXPERIMENTS.md §Perf iteration A2.)
+        # all-to-all.)
         "experts": "model",
         "embed": None,
         "embed_tp": "model",   # alternative: shard d_model itself (decode TP)
@@ -85,3 +95,80 @@ def batch_pspec(rules: ShardingRules) -> P:
 
 def act_pspec(rules: ShardingRules, *axes) -> P:
     return rules.pspec(axes)
+
+
+# -- activation-sharding context ---------------------------------------------
+# Model code is mesh-agnostic; launchers activate (mesh, rules) here and the
+# layers call :func:`constrain` on intermediate activations.  Without an
+# active context, constrain is a no-op (single-device tests).  This is the
+# GSPMD discipline that keeps the partitioner from replicating intermediates
+# inside remat'd scan bodies (observed: an unconstrained forward
+# attention-score dot materialized the full global batch per device — 17x
+# FLOP inflation).
+
+_ACTIVE: ContextVar[Optional[Tuple[object, object]]] = ContextVar(
+    "repro_sharding_ctx", default=None)
+
+
+@contextlib.contextmanager
+def use_sharding(mesh, rules):
+    token = _ACTIVE.set((mesh, rules))
+    try:
+        yield
+    finally:
+        _ACTIVE.reset(token)
+
+
+def constrain(x, *axes):
+    """Constrain ``x``'s sharding by logical axis names (None = replicated)."""
+    active = _ACTIVE.get()
+    if active is None:
+        return x
+    mesh, rules = active
+    pspec = logical_to_pspec(axes, rules.rules, x.shape, mesh)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))
+
+
+# -- elastic restart of a parameter tree --------------------------------------
+# Checkpoints store *global* tensors (see checkpoint.py), so elasticity
+# reduces to: build the largest usable mesh from the surviving devices,
+# re-derive shardings from the same logical rules, and restore.
+
+
+def elastic_mesh(
+    devices: Optional[Sequence] = None,
+    model_axis: int = 16,
+    axis_names: Tuple[str, str] = ("data", "model"),
+):
+    """Largest (data, model) mesh on the surviving devices.
+
+    Keeps the model axis at ``model_axis`` if possible (TP degree is baked
+    into compiled kernels' efficiency, so prefer shedding data parallelism);
+    otherwise falls back to the largest power-of-two split.
+    """
+    devices = list(devices if devices is not None else jax.devices())
+    n = len(devices)
+    model = model_axis
+    while model > 1 and n % model:
+        model //= 2
+    data = n // model
+    usable = data * model
+    mesh_devices = np.asarray(devices[:usable]).reshape(data, model)
+    return jax.sharding.Mesh(mesh_devices, axis_names)
+
+
+def resume(ckpt_dir: str, abstract_state, mesh=None, rules: ShardingRules = None,
+           step: Optional[int] = None):
+    """Restore ``abstract_state`` (tree of ParamSpec) onto ``mesh``.
+
+    Returns (state_tree, step, extra) with every tensor device_put with the
+    sharding the current mesh dictates — regardless of the mesh that saved it.
+    """
+    mesh = mesh if mesh is not None else elastic_mesh()
+    rules = rules or default_rules()
+    sh = shardings(abstract_state, mesh, rules.rules)
+    like = shape_structs(abstract_state)
+    out = ckpt.restore(ckpt_dir, like, step=step, shardings=sh)
+    if out is None:
+        raise FileNotFoundError(f"no checkpoint in {ckpt_dir}")
+    return out

@@ -1,10 +1,10 @@
 """JAX's persistent compilation cache, for the entry points.
 
 Entry points (``chip_smoke.py``, ``examples/mine_t10.py``,
-``python -m repro.launch.serve``, the ``launch.multihost`` worker and
-``benchmarks/run.py``) call :func:`enable_compile_cache` once before their
-first compile; importing the package never does.  A compiled program is
-found again only under the same cache path, so the path is fixed.
+``python -m repro.launch.serve``, the ``launch.multihost`` worker and the
+chip benchmark's ``bench/harness.py``) call :func:`enable_compile_cache` once
+before their first compile; importing the package never does.  A compiled
+program is found again only under the same cache path, so the path is fixed.
 """
 
 from __future__ import annotations

@@ -53,8 +53,8 @@ def _probe_costs(cfg, shape, mesh, rules, sh):
     probe_chunk = max(1024, sh.seq_len // 8 if sh.kind != "decode" else 1024)
 
     # SSD probes: cap the number of unrolled chunks at 16 (the within-chunk
-    # decay terms scale with Q, inflating those ~5%-of-layer terms; noted in
-    # EXPERIMENTS.md methodology). Keeps probe HLOs compilable in minutes.
+    # decay terms scale with Q, inflating those ~5%-of-layer terms). Keeps
+    # probe HLOs compilable in minutes.
     ssm = cfg.ssm
     if ssm is not None and sh.kind != "decode" and sh.seq_len // ssm.chunk > 16:
         ssm = dataclasses.replace(ssm, chunk=sh.seq_len // 16)

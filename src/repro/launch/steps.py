@@ -7,10 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.ctx import use_sharding
-
 from repro.configs.base import LM_SHAPES, ModelConfig
-from repro.distributed.sharding import ShardingRules
+from repro.distributed.sharding import ShardingRules, use_sharding
 from repro.launch import specs as S
 from repro.models import model as M
 from repro.train import OptConfig

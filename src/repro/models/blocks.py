@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.ctx import constrain
+from repro.distributed.sharding import constrain
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import moe as moe_mod

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.ctx import constrain
+from repro.distributed.sharding import constrain
 from repro.models.params import spec
 from repro.models.layers import mlp_abstract, mlp, _act
 

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.ctx import use_sharding
+from repro.distributed.sharding import use_sharding
 from repro.models import model as M
 from repro.models.params import materialize
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed import checkpoint as ckpt
-from repro.distributed.ctx import use_sharding
+from repro.distributed.sharding import use_sharding
 from repro.models import model as M
 from repro.models.params import materialize, shardings as mk_shardings
 from repro.train.optimizer import OptConfig

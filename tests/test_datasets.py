@@ -1,7 +1,8 @@
 """Dataset subsystem (repro.data.datasets) + sweep-grid plumbing tests:
 Quest-name parsing, registry, seeded determinism, .dat round-trips with the
 sidecar dense cache, T/I/D parameter sanity, adversarial generator shapes,
-and per-cell JobProfile aggregation / cross-backend parity cells."""
+and per-cell JobProfile aggregation / parity-cell divergence detection
+(the grid of parity cells itself is ``tests/test_paper_grid.py``)."""
 
 import gzip
 import os
@@ -206,22 +207,6 @@ def test_itemset_digest_canonical():
     assert itemset_digest(a) == itemset_digest(b)
     assert itemset_digest(a) != itemset_digest({(1, 2): 6, (3,): 7})
     assert itemset_digest(a) != itemset_digest({(1, 2): 5})
-
-
-def test_run_parity_cell_backends_agree():
-    from repro.core.runtime import JaxRunner, SimRunner
-
-    db = get_dataset("T10I4D100K", scale=0.0015, seed=9)
-    cell = run_parity_cell(db, 0.03, {
-        "sim": lambda: SimRunner(structure="hash_tree", n_mappers=3),
-        "jax": lambda: JaxRunner(store="perfect_hash"),
-    }, max_k=4)
-    assert set(cell.backends) == {"sim", "jax"}
-    assert cell.n_itemsets > 0
-    assert len(cell.digest) == 16
-    # The sim cell keeps the paper's cluster model, the jax cell wall time.
-    assert cell.backends["sim"]["parallel_seconds"] > 0
-    assert cell.backends["jax"]["seconds"] > 0
 
 
 def test_run_parity_cell_detects_divergence():

@@ -15,7 +15,7 @@ from repro.core.engine import MapReduceEngine
 from repro.core.itemsets import level_to_matrix
 from repro.core.stores import encode_db
 from repro.distributed import checkpoint as ckpt
-from repro.distributed.elastic import elastic_mesh, resume
+from repro.distributed.sharding import elastic_mesh, resume
 from repro.models import model as M
 from repro.models.params import materialize, spec
 

@@ -211,9 +211,14 @@ def test_retry_exhaustion_raises_job_failed(db, executor):
     assert runner._pool is None
 
 
-def test_retry_disabled_fast_path_propagates_crash(db):
-    """retry=None is the pre-fault-tolerance fast path: injected faults are
-    not caught, and the pool is still closed on the way out."""
+def test_retry_disabled_fast_path_propagates_crash(db, clean):
+    """retry=None is the pre-fault-tolerance fast path: a clean run counts
+    exactly as the recovery layer's does, injected faults are not caught,
+    and the pool is still closed on the way out."""
+    with SimRunner(structure="trie", executor="thread", retry=None) as runner:
+        res = FrequentItemsetMiner(min_support=MIN_SUPPORT,
+                                   runner=runner).mine(db)
+    assert res.itemsets == clean.itemsets
     plan = FaultPlan(F.crash(k=2, slot=0))
     runner = SimRunner(structure="trie", executor="thread", fault_plan=plan,
                        retry=None)

@@ -18,10 +18,12 @@ from repro.core.itemsets import (
 )
 from repro.core.stores import ARRAY_STORES, encode_db, pack_bitmap
 from repro.core.stores.base import pad_candidates
+from repro.core.stores.bitmap import candidates_to_khot
 from repro.core.stores.packed_bitmap import pack_candidates_device
 from repro.kernels.support_count import (
     packed_support_count,
     packed_support_count_ref,
+    support_count_ref,
 )
 
 
@@ -95,7 +97,7 @@ def test_packed_view_matches_bitmap():
 
 
 def test_device_candidate_encoder_matches_host_khot():
-    from repro.core.stores.bitmap import BitmapMXUStore, candidates_to_khot
+    from repro.core.stores.bitmap import BitmapMXUStore
 
     rng = np.random.default_rng(1)
     f_pad = 256
@@ -141,6 +143,11 @@ def test_packed_kernel_matches_ref(n, w, c, k):
     got = np.asarray(packed_support_count(
         packed, cpacked, kvec, block_n=128, block_c=128, block_w=4))
     np.testing.assert_array_equal(ref, got)
+    # The packed oracle agrees with the dense k-hot matmul oracle.
+    khot, _ = candidates_to_khot(cand, f)
+    dense = np.asarray(support_count_ref(bitmap.astype(np.float32), khot,
+                                         kvec))
+    np.testing.assert_array_equal(ref, dense)
 
 
 def test_apriori_gen_matrix_matches_python():

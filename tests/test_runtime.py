@@ -170,11 +170,22 @@ def test_hadoop_sim_executor_passthrough(t10_db, oracle):
 
 
 # -- async double-buffered wave dispatch -----------------------------------
-def _c2_wave(db):
-    # Shared wave recipe with the benchmark suites (min_count 5 on N=300).
-    from benchmarks.common import c2_wave
+def _c2_wave(db, min_count: int = 5):
+    """One C2 counting wave over ``db``: dense-remap the items, keep those
+    with at least ``min_count`` baskets, and join them into candidate pairs.
 
-    return c2_wave(db, min_frac=5 / len(db))
+    Returns ``(dense db, n_items, (C, 2) candidate matrix)``.
+    """
+    from collections import Counter
+
+    from repro.core.itemsets import apriori_gen, sort_level
+
+    items = sorted({i for t in db for i in t})
+    remap = {it: i for i, it in enumerate(items)}
+    db_dense = [[remap[i] for i in t] for t in db]
+    c1 = Counter(i for t in db_dense for i in t)
+    l1 = sort_level((i,) for i, c in c1.items() if c >= min_count)
+    return db_dense, len(items), level_to_matrix(apriori_gen(l1))
 
 
 def test_pipeline_determinism_engine(t10_db):

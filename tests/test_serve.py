@@ -352,6 +352,30 @@ def test_stale_serving_certificates_validate_against_recount():
     svc.close()
 
 
+def test_query_during_background_refresh_does_not_join_it():
+    """A staleness-budget query issued while a background refresh is in
+    flight is answered from the tracked lattice and leaves that refresh in
+    flight; an exact query over the service's own budget refreshes."""
+    rng = np.random.default_rng(17)
+    svc = MiningService(min_support=0.08, store="perfect_hash", n_slots=8,
+                        slot_size=32, staleness=0.02, max_k=6)
+    svc.ingest([t for b in _batches(rng, 8, 32, n_items=24) for t in b])
+    svc.query()                      # cold refresh builds the lattice
+    for batch in _batches(rng, 3, 32, n_items=24):
+        svc.ingest(batch)
+        r0 = svc.refreshes
+        assert svc.refresh_async(), "the refresh ended in its first unit"
+        res = svc.query(staleness=4.0)
+        assert not res.refreshed and res.refresh_in_flight
+        assert svc.stats()["refresh_in_flight"] and svc.refreshes == r0
+        _validate_certificate(svc.window(), 0.08, 6, res)
+        res = svc.query()            # over the 0.02 budget: exact
+        assert res.refreshed and res.stale_reason == "drift"
+        assert res.itemsets == _oracle(svc.window(), 0.08, 6)
+        assert not svc.stats()["refresh_in_flight"]
+    svc.close()
+
+
 def test_stale_query_exact_when_bound_is_zero():
     """With zero churn since refresh the certificate certifies exactness —
     and the answer really is the oracle's."""
