@@ -178,6 +178,11 @@ class MapReduceEngine:
         # data axes (waves and Job1) since construction; a runner reads the
         # difference across one job's dispatches (JobProfile.reduce_bytes).
         self.psum_bytes = 0
+        # Chunk counts dispatched whose ``count_block`` takes the store's
+        # Gram path (``pair_by_gram`` on the shapes each device sees), since
+        # construction; a runner reads the difference across one job's
+        # dispatch (JobProfile.gram_chunks).
+        self.gram_chunks = 0
         # Cross-place() compile caches: re-placing the same engine (repeat
         # mines, benchmark rounds) must not rebuild identical encode/count
         # jits — mesh and store are fixed per engine, so f_pad (encode) and
@@ -336,6 +341,8 @@ class MapReduceEngine:
         (and no replicated full-wave encode) happens in between."""
         cand_p = pad_candidates(chunk, self._enc.f_pad,
                                 shards=self.n_cand_shards)
+        self._note_gram(cand_p.shape[1], self._enc.f_pad,
+                        cand_p.shape[0] // self.n_cand_shards)
         cand_np = np.ascontiguousarray(cand_p, dtype=np.int32)
         if self._cand_in_sharding is not None:
             # device_put straight from host memory: a committed single-device
@@ -345,6 +352,13 @@ class MapReduceEngine:
         else:
             cand_dev = jnp.asarray(cand_np)
         return self._encode_jit(cand_dev)
+
+    def _note_gram(self, k: int, f_pad: int, c: int) -> None:
+        """Count a chunk whose (C = ``c``, k) candidates each device counts
+        by the store's Gram path; a store without ``pair_by_gram`` has none."""
+        pair_by_gram = getattr(self.store, "pair_by_gram", None)
+        if pair_by_gram is not None and pair_by_gram(k, f_pad, c):
+            self.gram_chunks += 1
 
     def _dispatch_count(self, cands: dict):
         """Dispatch the count of an already-encoded chunk (non-blocking)."""
@@ -543,6 +557,7 @@ class MapReduceEngine:
         for slot, i in enumerate(starts):
             chunk = cand[i : i + self.cand_block]
             cand_p = pad_candidates(chunk, enc_block.f_pad)
+            self._note_gram(cand_p.shape[1], enc_block.f_pad, cand_p.shape[0])
             dev = count(trans, encode(jnp.asarray(cand_p, dtype=jnp.int32)))
             self._queue.append((pending, slot, dev, chunk.shape[0]))
             while len(self._queue) > self.inflight:

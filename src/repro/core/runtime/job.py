@@ -50,6 +50,12 @@ bytes of partial-count vector each device contributed to the job's
 the padded chunk length x 4 for a count wave (a device's candidate shard
 under ``cand`` sharding), n_items x 4 for Job1.  One device: 1 and 0.
 
+``gram_chunks`` counts the job's chunk dispatches whose store count took
+the Gram path (``PerfectHashStore.pair_by_gram``: k = 2 and F_pad² within
+``GRAM_RATIO`` x the chunk's padded C) — the ``gram`` argument of the job's
+``repro.dispatch`` span; 0 at k >= 3, for the other stores and on the
+simulator.
+
 Fault-tolerance telemetry (all zero on a clean run with recovery enabled —
 the fields record what the recovery layer *did*, not what it cost):
 ``retries`` counts failed task attempts that were re-run (crashes and
@@ -99,6 +105,9 @@ class JobProfile:
     # one device, where no psum runs).
     data_shards: int = 1
     reduce_bytes: int = 0
+    # Store-count telemetry: the job's chunk counts the store counted by
+    # its Gram path (perfect_hash at k = 2, ``pair_by_gram``); 0 elsewhere.
+    gram_chunks: int = 0
 
     @property
     def parallel_seconds(self) -> float:

@@ -654,11 +654,12 @@ class _ChunkedPending:
     """
 
     def __init__(self, runner: "JaxRunner", job: CountJob, parts,
-                 encode_s: float) -> None:
+                 encode_s: float, gram_chunks: int) -> None:
         self._runner = runner
         self._job = job
         self._parts = parts
         self._encode_s = encode_s
+        self._gram_chunks = gram_chunks
 
     def poll(self) -> bool:
         self._runner.engine.drain_ready()
@@ -677,6 +678,7 @@ class _ChunkedPending:
             inflight_depth=self._runner.engine.inflight,
             inflight_retunes=self._runner.engine.inflight_retunes,
             chunks=len(self._parts),
+            gram_chunks=self._gram_chunks,
         )
         return total, prof
 
@@ -685,12 +687,14 @@ class _JaxPending:
     """Async-job handle: blocks on the engine FIFO, then fills the profile."""
 
     def __init__(self, runner: "JaxRunner", job: CountJob, pending,
-                 encode_s: float, reduce_bytes: int) -> None:
+                 encode_s: float, reduce_bytes: int,
+                 gram_chunks: int) -> None:
         self._runner = runner
         self._job = job
         self._pending = pending
         self._encode_s = encode_s
         self._reduce_bytes = reduce_bytes
+        self._gram_chunks = gram_chunks
 
     def poll(self) -> bool:
         """Non-blocking: drain whatever the device has finished, report
@@ -709,6 +713,7 @@ class _JaxPending:
             inflight_retunes=self._runner.engine.inflight_retunes,
             data_shards=self._runner.engine.n_data_shards,
             reduce_bytes=self._reduce_bytes,
+            gram_chunks=self._gram_chunks,
         )
         return counts, prof
 
@@ -909,11 +914,13 @@ class JaxRunner(BaseRunner):
                 raise DeviceLostError(lost=spec.lost, k=job.k)
         if self._reader is not None:
             return self._count_chunked_async(job)
-        psum_bytes = self.engine.psum_bytes
+        psum_bytes, gram = self.engine.psum_bytes, self.engine.gram_chunks
         with span("repro.dispatch", k=job.k, rows=job.n_candidates) as d:
             pending = self.engine.count_candidates_async(job.cand)
+            gram = self.engine.gram_chunks - gram
+            d.note(gram=gram)
         return _JaxPending(self, job, pending, d.seconds,
-                           self.engine.psum_bytes - psum_bytes)
+                           self.engine.psum_bytes - psum_bytes, gram)
 
     def _count_chunked_async(self, job: CountJob) -> _ChunkedPending:
         """Stream the reader through the wave: per chunk, the serving path's
@@ -923,12 +930,14 @@ class JaxRunner(BaseRunner):
         once ``inflight`` chunk counts are outstanding, so dispatch never
         runs ahead of the device by more than the queue."""
         assert self._chunk_item_map is not None, "call place(item_map) first"
-        parts = []
+        parts, gram = [], self.engine.gram_chunks
         with span("repro.dispatch", k=job.k, rows=job.n_candidates) as d:
             for chunk in self._reader.chunks():
                 enc = self.encode_block(chunk, self._chunk_item_map)
                 parts.append(self.engine.count_block_async(enc, job.cand))
-        return _ChunkedPending(self, job, parts, d.seconds)
+            gram = self.engine.gram_chunks - gram
+            d.note(gram=gram)
+        return _ChunkedPending(self, job, parts, d.seconds, gram)
 
 
 class ShardedRunner(JaxRunner):

@@ -7,6 +7,7 @@ fixed-shape array program suitable for jit/shard_map:
 paper structure    store                  per-level matching primitive
 =================  =====================  =========================================
 hash-table trie    ``perfect_hash``       one O(1) gather into the transaction bitmap
+                                          (k = 2: the block's Gram matrix BᵀB on the MXU)
 trie               ``sorted_prefix``      binary search in the sorted transaction
 hash tree          ``hash_bucket``        bucket probe + linear scan over the bucket
 (beyond paper)     ``bitmap``             dense (T·Cᵀ == k) matmul on the MXU

@@ -208,7 +208,7 @@ class DeltaCountMixin:
     window's counts are maintained exactly by adding the contribution of an
     ingested block and subtracting the contribution of an evicted block:
     ``count(window') = count(window) + count(added) - count(removed)``.
-    Both directions reuse the store's own ``count_block`` (same gathers,
+    Both directions reuse the store's own ``count_block`` (same path,
     same integer adds), so delta-maintained counts are bit-identical to a
     full recount at every step.
     """

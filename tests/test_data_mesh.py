@@ -132,6 +132,35 @@ assert all(lv.data_shards == 1 and lv.reduce_bytes == 0
            for lv in one.levels)
 """
 
+_GRAM = """
+import itertools
+from repro.core.runtime import ShardedRunner
+from repro.core.runtime.engine import MapReduceEngine
+from repro.core.stores import encode_db
+
+# A mine through ShardedRunner: each shard counts every k = 2 chunk by the
+# Gram path (F_pad 128), no deeper one; the result equals the reference.
+m = miner(mesh=MESH, cand_block=128)
+res = m.mine(TX)
+assert isinstance(m.active_runner, ShardedRunner)
+assert res.itemsets == reference.frequent_itemsets(MAT, SUPPORT)
+job1, c2, *deeper = res.levels
+assert c2.gram_chunks == -(-c2.n_candidates // 128) > 1, c2
+assert deeper and all(lv.gram_chunks == 0 for lv in deeper)
+
+# Both sides of the predicate on the mesh, over F_pad 384: chunks of 512
+# pairs by the Gram path, of 128 by the gathers; both psum to the counts.
+enc = encode_db(TX, n_items=300)
+pairs = np.array(list(itertools.combinations(range(120), 2)), np.int32)
+want = (enc.bitmap[:, pairs[:, 0]] & enc.bitmap[:, pairs[:, 1]]).sum(axis=0)
+for cand_block, gram in ((512, -(-len(pairs) // 512)), (128, 0)):
+    eng = MapReduceEngine(mesh=MESH, block_n=BLOCK_N, cand_block=cand_block)
+    eng.place(enc)
+    got = eng.count_candidates(pairs)
+    assert (got == want).all(), cand_block
+    assert eng.gram_chunks == gram, (cand_block, eng.gram_chunks)
+"""
+
 
 def _four_devices(code: str) -> subprocess.CompletedProcess:
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -144,10 +173,11 @@ def _four_devices(code: str) -> subprocess.CompletedProcess:
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
 
 
-@pytest.mark.parametrize("code", [_EQUALS, _SHARES, _COUNTERS],
+@pytest.mark.parametrize("code", [_EQUALS, _SHARES, _COUNTERS, _GRAM],
                          ids=["mine_equals_reference_and_one_device",
                               "shard_partials_add_up_to_one_device_counts",
-                              "data_shards_reduce_bytes_and_put_spans"])
+                              "data_shards_reduce_bytes_and_put_spans",
+                              "gram_and_gather_pair_counts_on_the_mesh"])
 def test_data_mesh_on_four_devices(code):
     out = _four_devices(code)
     assert out.returncode == 0, out.stderr[-3000:]

@@ -141,3 +141,64 @@ def test_ingest_span_counts_rows_items_and_dups(tmp_path):
             if ev.name.startswith("repro.ingest")}
     assert args["repro.ingest"] == {"rows": 200}
     assert args["repro.ingest.pack"] == {"items": items, "dups": dups}
+
+
+def test_dispatch_span_and_job_profile_count_gram_chunks(tmp_path):
+    """A T10-shaped wave in small: full chunks of pairs, a tail chunk, then
+    a k = 3 wave.  The ``gram`` argument of each ``repro.dispatch`` and the
+    job's ``gram_chunks`` count the chunks ``pair_by_gram`` sends to the
+    Gram path: over F_pad 384, chunks of 512 pairs do (384² <= 512 x 512
+    at ``GRAM_RATIO`` 512), the tail padded to 128 does not, and k = 3
+    never does."""
+    import itertools
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.itemsets import brute_force_counts
+    from repro.core.runtime import JaxRunner
+    from repro.core.runtime.job import CountJob
+    from repro.core.stores.perfect_hash import PerfectHashStore
+
+    f_pad = 384
+    assert PerfectHashStore.pair_by_gram(2, f_pad, 512)
+    assert not PerfectHashStore.pair_by_gram(2, f_pad, 128)
+    db = _baskets(4, 150, n_items=300, max_len=12)
+    runner = JaxRunner(cand_block=512, block_n=64)
+    runner.ingest(db)
+    runner.place(np.arange(300))                # F = 300: F_pad 384
+    pairs = np.array(
+        list(itertools.combinations(range(300), 2))[:3 * 512 + 100], np.int32)
+    triples = np.array(list(itertools.combinations(range(40), 3))[:600],
+                       np.int32)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        got = [runner.count_async(CountJob(k=c.shape[1], cand=c)).result()
+               for c in (pairs, triples)]
+    finally:
+        jax.profiler.stop_trace()
+    (c2, p2), (c3, p3) = got
+    assert (p2.gram_chunks, p3.gram_chunks) == (3, 0)
+    for counts, cand in ((c2, pairs), (c3, triples)):
+        want = brute_force_counts(db, [tuple(r) for r in cand.tolist()])
+        np.testing.assert_array_equal(counts, list(want.values()))
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    events = sorted((ev.start_ns, dict(ev.stats))
+                    for plane in ProfileData.from_file(str(path)).planes
+                    if plane.name.startswith("/host:")
+                    for line in plane.lines for ev in line.events
+                    if ev.name == "repro.dispatch")
+    assert [args for _, args in events] == [
+        {"k": 2, "rows": 1636, "gram": 3}, {"k": 3, "rows": 600, "gram": 0}]
+    runner.close()
+
+    # A whole small mine: every k = 2 chunk by the Gram path (F_pad 128),
+    # none deeper.
+    res = FrequentItemsetMiner(min_support=0.02, store="perfect_hash",
+                               cand_block=128).mine(_baskets(0, 300))
+    job1, c2, *deeper = res.levels
+    assert c2.gram_chunks == -(-c2.n_candidates // 128) > 1
+    assert deeper and all(lv.gram_chunks == 0 for lv in deeper)
